@@ -1,14 +1,14 @@
 """Kernel-layer benchmarks.
 
-The Pallas kernels target TPU (validated via interpret mode — wall time in
-interpret is NOT hardware-representative). What IS measurable here: the XLA
-flash path vs naive masked attention (same math, different blocking) on the
-real backend, and the persistent executors' descriptor-dispatch rates.
+The Pallas kernels compile on the TPU and run in interpret mode on the CPU
+(wall time in interpret is NOT hardware-representative; the drain row says
+which ran). Measured: the XLA flash path vs naive masked attention (same
+math, different blocking) on the real backend, and the persistent
+runtimes' descriptor-dispatch rates.
 
 Rows:
   attn_flash_xla_us              — flash-blocked causal attention
   attn_masked_full_us            — naive masked attention (flash_speedup)
-  persistent_exec_op_us          — legacy work-queue executor, per op
   kernel_persistent_desc_per_sec — drain megakernel descriptor rate: ONE
                                    compiled launch retiring a full
                                    device-resident queue
@@ -42,10 +42,10 @@ from repro.core.mega import MegaRuntime, mega_work_classes
 from repro.core.sched import EdfPolicy
 from repro.core.telemetry import (EV_CHUNK_RETIRE, EV_TRIGGER, LogHistogram,
                                   TraceCollector)
-from repro.kernels.persistent import (OP_MATMUL, OP_RELU, TILE,
-                                      TILE_RESULT_TEMPLATE, build_queue,
-                                      pack_args, persistent_drain,
-                                      persistent_execute, tile_state)
+from repro.kernels import default_interpret
+from repro.kernels.persistent import (OP_MATMUL, OP_RELU,
+                                      TILE_RESULT_TEMPLATE, pack_args,
+                                      persistent_drain, tile_state)
 from repro.models.attention import flash_xla, masked_full_xla
 from repro.system import LkSystem
 
@@ -78,20 +78,6 @@ def _attn_rows(smoke: bool) -> list[str]:
     rows.append(f"attn_flash_xla_us,{t_flash*1e6:.0f},S={S}")
     rows.append(f"attn_masked_full_us,{t_masked*1e6:.0f},"
                 f"flash_speedup={t_masked/t_flash:.2f}")
-
-    # legacy persistent executor: descriptors/second through one launch
-    C, NBUF, QL = 1, 4, 8
-    ws = jnp.asarray(rng.normal(size=(C, NBUF, TILE, TILE)), jnp.float32)
-    prog = [[(OP_MATMUL, *pack_args(3, 0, 1))] * QL]
-    queue = jnp.asarray(build_queue(prog, QL))
-    out = persistent_execute(queue, ws, interpret=True)
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    out = persistent_execute(queue, ws, interpret=True)
-    jax.block_until_ready(out)
-    dt = time.perf_counter() - t0
-    rows.append(f"persistent_exec_op_us,{dt/QL*1e6:.0f},"
-                f"interpret_mode=1,ops={QL}")
     return rows
 
 
@@ -106,16 +92,17 @@ def _drain_rate_row(smoke: bool) -> str:
     ctrl = jnp.asarray(mb.queue_control(tail=Q))[None]
     ws = jnp.asarray(tile_state(4, seed=0)["ws"])[None]
     carry = jnp.zeros((1, 1), jnp.float32)
-    out = persistent_drain(ctrl, ring, ws, carry, interpret=True)
+    out = persistent_drain(ctrl, ring, ws, carry)
     jax.block_until_ready(out)
     t0 = time.perf_counter()
     for _ in range(reps):
-        out = persistent_drain(ctrl, ring, ws, carry, interpret=True)
+        out = persistent_drain(ctrl, ring, ws, carry)
     jax.block_until_ready(out)
     dt = time.perf_counter() - t0
     rate = Q * reps / dt
     return (f"kernel_persistent_desc_per_sec,{rate:.0f},"
-            f"queue_rows={Q},launch_us={dt/reps*1e6:.0f},interpret_mode=1")
+            f"queue_rows={Q},launch_us={dt/reps*1e6:.0f},"
+            f"interpret_mode={int(default_interpret())}")
 
 
 def _mega_system(runtime: str, max_steps: int, n_items: int,

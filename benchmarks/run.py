@@ -20,7 +20,8 @@
 #   bench_kernels     -> flash-vs-masked attention, executor dispatch rate
 #
 # ``--smoke`` is the CI fast path: every module runs with reduced reps so
-# bench code cannot silently rot, and NO JSON artifact is written.
+# bench code cannot silently rot, and NO JSON artifact is written. In
+# every mode a module that raises makes the run exit 1.
 #
 # Roofline terms come from the dry-run (python -m repro.launch.roofline),
 # not from wall time — this container is CPU-only.
@@ -108,6 +109,8 @@ def main(argv=None) -> None:
     explicit_json = args.json_path is not None
     if args.json_path is None:
         args.json_path = default_json_path()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_dispatch, bench_elastic, bench_kernels,
                             bench_serving, bench_throughput)
     prev = _prev_values()
@@ -132,15 +135,13 @@ def main(argv=None) -> None:
     if args.smoke and not explicit_json:
         print(f"# smoke: {len(records)} rows, no JSON written",
               file=sys.stderr)
-        if failures:   # CI signal: bench code rotted
-            sys.exit(1)
-        return
-    with open(args.json_path, "w") as f:
-        json.dump(records, f, indent=2)
-        f.write("\n")
-    print(f"# wrote {len(records)} rows to {args.json_path}",
-          file=sys.stderr)
-    if args.smoke and failures:   # CI signal: bench code rotted
+    else:
+        with open(args.json_path, "w") as f:
+            json.dump(records, f, indent=2)
+            f.write("\n")
+        print(f"# wrote {len(records)} rows to {args.json_path}",
+              file=sys.stderr)
+    if failures:   # a module raised: its rows are ERROR rows, not results
         sys.exit(1)
 
 

@@ -310,6 +310,8 @@ class Dispatcher:
         self.coalesced_triggers = 0  # items that rode a batched doorbell
         self.chunk_protocol_errors = 0   # chunked work on a runtime
         #                                  whose from_gpu can't say so
+        self.failed_clusters = 0   # clusters retired by _fail_cluster
+        self.replayed = 0          # items it requeued onto survivors
         self._n_completed = 0
         self._n_met = 0
         self._n_stragglers = 0
@@ -399,6 +401,8 @@ class Dispatcher:
             "stragglers": self._n_stragglers,
             "ack_mismatches": self.mailbox.ack_mismatches,
             "chunk_protocol_errors": self.chunk_protocol_errors,
+            "failed_clusters": self.failed_clusters,
+            "replayed": self.replayed,
             "failure_callback_errors": len(self.failure_callback_errors),
             "recarves": self.recarves,
             "recarve_rejected": self.recarve_rejected,
@@ -941,6 +945,7 @@ class Dispatcher:
         inflight_descs = self.mailbox.pending(cluster)
         inflight_meta = list(self._inflight.pop(cluster, ()))
         queued = self.policy.drop_cluster(cluster)
+        self.failed_clusters += 1
         if self.telemetry is not None:
             self.telemetry.emit(
                 EV_FAIL, t_us=self._clock(), cluster=cluster,
@@ -984,6 +989,7 @@ class Dispatcher:
                 continue
             tgt = min(self._placement_pool(), key=self._load)
             self.policy.enqueue(tgt, it)
+            self.replayed += 1
             if it.ticket is not None:
                 it.ticket.cluster = tgt
             if self.telemetry is not None:
@@ -1203,6 +1209,10 @@ class Dispatcher:
             # protocol discrepancies the operator must see in one place
             "ack_mismatches": self.mailbox.ack_mismatches,
             "chunk_protocol_errors": self.chunk_protocol_errors,
+            # cluster failures and the items replayed off them: a healthy
+            # run keeps both at zero
+            "failed_clusters": self.failed_clusters,
+            "replayed": self.replayed,
             # elastic repartition outcomes (applied / refused-by-admission)
             "recarves": self.recarves,
             "recarve_rejected": self.recarve_rejected,

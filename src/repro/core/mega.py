@@ -42,10 +42,12 @@ import numpy as np
 from repro.core import mailbox as mb
 from repro.core import persistent as P
 from repro.core.persistent import (ExecutableCache, _Block,
-                                   _PipelinedRuntime, _tree_key)
+                                   _device_key, _PipelinedRuntime,
+                                   _tree_key)
 from repro.core.telemetry import EV_RT_TRIGGER, TraceCollector
 from repro.core.telemetry.events import now_us
 from repro.core.wcet import WcetTracker
+from repro.kernels import default_interpret
 from repro.kernels.persistent import kernel as K
 from repro.kernels.persistent.ops import TILE_OP_NAMES, tile_work_table
 
@@ -62,8 +64,11 @@ class MegaRuntime(_PipelinedRuntime):
     device queue capacity Q; ``boot(state)`` takes the tile state tree
     ``{"ws": (nbuf, TILE, TILE) f32}`` (``tile_state()``) and compiles
     the drain ``pallas_call`` once (shared ``exec_cache`` turns recarve
-    reboots into dictionary hits). ``interpret=None`` auto-selects
-    pallas interpret mode off-TPU, like ``ops.persistent_execute``.
+    reboots into dictionary hits). ``interpret=None`` compiles the kernel
+    on the TPU and interprets it on the CPU backend only
+    (``repro.kernels.default_interpret``); ``interpreted`` records which
+    ran. ``device`` places the workspace, carry, queues and the compiled
+    kernel on one device (a cluster's chip); None keeps JAX's default.
     """
 
     def __init__(self, *, tracker: Optional[WcetTracker] = None,
@@ -72,7 +77,8 @@ class MegaRuntime(_PipelinedRuntime):
                  telemetry: Optional[TraceCollector] = None,
                  exec_cache: Optional[ExecutableCache] = None,
                  interpret: Optional[bool] = None,
-                 profile: Optional[bool] = None):
+                 profile: Optional[bool] = None,
+                 device=None):
         super().__init__(tracker=tracker, max_inflight=max_inflight,
                          telemetry=telemetry, name="mega")
         if max_steps < 1:
@@ -81,6 +87,8 @@ class MegaRuntime(_PipelinedRuntime):
         self.max_steps = int(max_steps)
         self._exec_cache = exec_cache
         self._interpret = interpret
+        self.interpreted: Optional[bool] = None   # set at boot
+        self.device = device
         # flight recorder (None = auto: on exactly when telemetry is
         # attached): boots the profiled drain kernel, whose extra
         # (Q, PROF_WIDTH) output and persistent tick counter join the
@@ -111,19 +119,22 @@ class MegaRuntime(_PipelinedRuntime):
                 raise ValueError(
                     "MegaRuntime state must be {'ws': (nbuf, "
                     f"{K.TILE}, {K.TILE}) f32}}, got ws{ws.shape}")
-            ws = jax.device_put(ws[None])             # add the cluster dim
-            carry = jax.device_put(jnp.zeros((1, 1), jnp.float32))
+            dev = self.device
+            ws = jax.device_put(ws[None], dev)        # add the cluster dim
+            carry = jax.device_put(np.zeros((1, 1), np.float32), dev)
             interpret = self._interpret
             if interpret is None:
-                interpret = jax.default_backend() != "tpu"
+                interpret = default_interpret()
+            self.interpreted = bool(interpret)
             if self._profile is None:
                 self._profile = self.telemetry is not None
-            tick0 = jax.device_put(jnp.zeros((1, 1), jnp.int32)) \
+            tick0 = jax.device_put(np.zeros((1, 1), np.int32), dev) \
                 if self._profile else None
             Q = self.max_steps
-            ctrl0 = jnp.zeros((1, mb.QCTRL_WIDTH), jnp.int32)
-            ring0 = jnp.asarray(
-                np.tile(mb.nop_descriptor(), (Q, 1)))[None]
+            ctrl0 = jax.device_put(np.zeros((1, mb.QCTRL_WIDTH), np.int32),
+                                   dev)
+            ring0 = jax.device_put(
+                np.tile(mb.nop_descriptor(), (1, Q, 1)), dev)
 
             def compile_drain():
                 fn = functools.partial(K.persistent_drain_pallas,
@@ -135,7 +146,7 @@ class MegaRuntime(_PipelinedRuntime):
                 return jax.jit(fn).lower(ctrl0, ring0, ws, carry).compile()
 
             key = ("mega_drain_prof" if self._profile else "mega_drain",
-                   _tree_key(ws), Q, bool(interpret),
+                   _tree_key(ws), Q, bool(interpret), _device_key(dev),
                    mb.DESC_WIDTH, mb.QCTRL_WIDTH)
             if self._exec_cache is not None:
                 self._drain = self._exec_cache.get_or_compile(
@@ -174,17 +185,18 @@ class MegaRuntime(_PipelinedRuntime):
             ring = mb.descriptor_ring(block, self.max_steps)
             ctrl = mb.queue_control(tail=len(block))
             with self.tracker.phase("trigger"):
+                ctrl_dev, ring_dev = jax.device_put(
+                    (ctrl[None], ring[None]), self.device)
                 prof = None
                 if self._profile:
                     (ws, carry, acks, results, ctrl_out, prof,
                      self._tick) = self._drain(
-                        jnp.asarray(ctrl)[None], jnp.asarray(ring)[None],
-                        self._ws, self._carry, self._tick)
+                        ctrl_dev, ring_dev, self._ws, self._carry,
+                        self._tick)
                     prof = prof[0]
                 else:
                     ws, carry, acks, results, ctrl_out = self._drain(
-                        jnp.asarray(ctrl)[None], jnp.asarray(ring)[None],
-                        self._ws, self._carry)
+                        ctrl_dev, ring_dev, self._ws, self._carry)
                 # async dispatch: return as soon as the drain is enqueued
                 self._ws = ws
                 self._carry = carry

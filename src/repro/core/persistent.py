@@ -86,6 +86,13 @@ def _tree_key(tree) -> tuple:
                   for leaf in leaves))
 
 
+def _device_key(device) -> Optional[tuple]:
+    """Hashable identity of the device a runtime is placed on (None = the
+    default device): compiled executables are bound to their device, so
+    it is part of every ExecutableCache key."""
+    return None if device is None else (device.platform, device.id)
+
+
 class ExecutableCache:
     """Shared cache of compiled persistent-step executables.
 
@@ -99,9 +106,10 @@ class ExecutableCache:
     the ORIGINAL work-fn objects (pre-``_normalize_work_fn``: the
     wrappers are per-runtime closures with fresh ids), the result
     template, the state/carries tree structure + leaf shapes/dtypes, the
-    donate flag, ``DESC_WIDTH``, and — for the multi-step ring variant —
-    ``max_steps``. Runtimes with a mesh/shardings bypass the cache
-    (sharded lowering bakes in device placement).
+    donate flag, the device the runtime is placed on, ``DESC_WIDTH``,
+    and — for the multi-step ring variant — ``max_steps``. Runtimes with a
+    mesh/shardings bypass the cache (sharded lowering bakes in device
+    placement).
 
     Not thread-safe; callers share it from one dispatch loop
     (``LkSystem`` passes one instance to every runtime it boots).
@@ -455,6 +463,11 @@ class PersistentRuntime(_PipelinedRuntime):
     served device-side, ``staged_misses`` counts mid-item re-triggers that
     had to pay a fresh host transfer because their staged entry was
     evicted (or staging is off).
+
+    ``device`` places the state, carries, descriptors and compiled
+    programs on one device (a cluster's chip); None keeps JAX's default
+    device. A ``mesh`` with ``state_shardings`` places the state by those
+    shardings instead.
     """
 
     def __init__(self, work_fns: Sequence[tuple],
@@ -468,7 +481,8 @@ class PersistentRuntime(_PipelinedRuntime):
                  telemetry: Optional[TraceCollector] = None,
                  exec_cache: Optional[ExecutableCache] = None,
                  staged_cap: int = 4,
-                 profile: Optional[bool] = None):
+                 profile: Optional[bool] = None,
+                 device=None):
         super().__init__(tracker=tracker, max_inflight=max_inflight,
                          telemetry=telemetry, name="lk")
         if max_steps < 1:
@@ -486,6 +500,7 @@ class PersistentRuntime(_PipelinedRuntime):
         self._result_template = result_template
         self.mesh = mesh
         self._state_shardings = state_shardings
+        self.device = device
         self._donate = donate
         self._exec_cache = exec_cache
         self._state = None
@@ -615,7 +630,7 @@ class PersistentRuntime(_PipelinedRuntime):
         runtimes with equal keys can share one compiled executable."""
         return (variant, self._orig_fns, _tree_key(self._result_template),
                 _tree_key(state), _tree_key(carries), bool(self._donate),
-                mb.DESC_WIDTH,
+                _device_key(self.device), mb.DESC_WIDTH,
                 self.max_steps if variant.startswith("multi") else 0)
 
     def boot(self, state) -> None:
@@ -633,20 +648,21 @@ class PersistentRuntime(_PipelinedRuntime):
             kwargs = {}
             if self._donate:
                 kwargs["donate_argnums"] = (0, 1)
-            desc0 = jnp.asarray(mb.nop_descriptor())
+            desc0 = jax.device_put(mb.nop_descriptor(), self.device)
             if self.mesh is not None and self._state_shardings is not None:
                 state = jax.device_put(state, self._state_shardings)
             else:
-                state = jax.device_put(state)
+                state = jax.device_put(state, self.device)
             # COPY the templates before donating: device_put on an array
             # already on device aliases it, and donation would delete the
             # caller's template out from under every other runtime booted
             # from the same object (LkSystem boots one per cluster)
             carries = jax.device_put(tuple(
-                jax.tree.map(jnp.array, t) for t in self._carry_templates))
+                jax.tree.map(jnp.array, t) for t in self._carry_templates),
+                self.device)
             if self._profile is None:
                 self._profile = self.telemetry is not None
-            tick0 = jax.device_put(jnp.zeros((), jnp.int32)) \
+            tick0 = jax.device_put(jnp.zeros((), jnp.int32), self.device) \
                 if self._profile else None
 
             def compile_step():
@@ -666,7 +682,8 @@ class PersistentRuntime(_PipelinedRuntime):
                 self._compiled = self._exec_cache.get_or_compile(
                     self._cache_key(variant, state, carries), compile_step)
                 self._advance = self._exec_cache.get_or_compile(
-                    ("advance", mb.DESC_WIDTH), compile_advance)
+                    ("advance", _device_key(self.device), mb.DESC_WIDTH),
+                    compile_advance)
             else:
                 self._compiled = compile_step()
                 # the double buffer's device-side descriptor advance
@@ -684,8 +701,9 @@ class PersistentRuntime(_PipelinedRuntime):
             kwargs = {}
             if self._donate:
                 kwargs["donate_argnums"] = (0, 1)
-            ring0 = jnp.asarray(
-                np.tile(mb.nop_descriptor(), (self.max_steps, 1)))
+            ring0 = jax.device_put(
+                np.tile(mb.nop_descriptor(), (self.max_steps, 1)),
+                self.device)
 
             def compile_multi():
                 if self._profile:
@@ -761,8 +779,8 @@ class PersistentRuntime(_PipelinedRuntime):
                     # (or staging is capped off): the fresh transfer below
                     # is exactly the cost the double buffer exists to hide
                     self.staged_misses += 1
-                dvec = jnp.asarray(enc if enc is not None
-                                   else desc.encode())
+                dvec = jax.device_put(
+                    enc if enc is not None else desc.encode(), self.device)
             self._stage_next(rid, chunk, n_chunks, dvec)
             prof = None
             if self._profile:
@@ -808,7 +826,7 @@ class PersistentRuntime(_PipelinedRuntime):
             block = descs[base:base + self.max_steps]
             ring = mb.descriptor_ring(block, self.max_steps)
             with self.tracker.phase("trigger"):
-                ring_dev = jnp.asarray(ring)
+                ring_dev = jax.device_put(ring, self.device)
                 profs = None
                 if self._profile:
                     (new_state, new_carries, self._tick, results, acks,

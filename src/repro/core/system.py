@@ -38,6 +38,8 @@ import itertools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Sequence, Union
 
+import jax
+
 from repro.core import mailbox as mb
 from repro.core.clusters import Cluster, ClusterManager
 from repro.core.dispatcher import Dispatcher, Ticket
@@ -101,6 +103,15 @@ class WorkClass:
                          period_us=self.period_us,
                          criticality=self.criticality,
                          chunk_us=self.chunk_us)
+
+
+def _placement(cl: Cluster):
+    """The device a cluster's runtime state lives on without explicit
+    shardings: the cluster's first device. Device stand-ins that are not
+    ``jax.Device`` objects (host-only tests) map to JAX's default
+    device."""
+    dev = cl.devices[0] if cl.n_devices else None
+    return dev if isinstance(dev, jax.Device) else None
 
 
 class LkSystem:
@@ -521,8 +532,12 @@ class LkSystem:
 
     def _add_cluster(self, cl: Cluster) -> int:
         did = next(self._next_dispatch_id)
-        if self._warm:
-            rt = self._warm.pop()
+        # a warm spare serves only a cluster on the device it was booted on
+        dev = _placement(cl)
+        spare = next((i for i, r in enumerate(self._warm)
+                      if r.device == dev), None)
+        if spare is not None:
+            rt = self._warm.pop(spare)
             self.warm_boots += 1
         else:
             rt = self._make_runtime(cl)
@@ -552,7 +567,8 @@ class LkSystem:
                 max_steps=self._max_steps,
                 telemetry=self.telemetry,
                 exec_cache=self.exec_cache,
-                profile=self._profile)
+                profile=self._profile,
+                device=_placement(cl))
             rt.boot(self._state_factory(cl))
             return rt
         shardings = (self._shardings_factory(cl)
@@ -569,7 +585,8 @@ class LkSystem:
             telemetry=self.telemetry,
             exec_cache=self.exec_cache,
             staged_cap=self._staged_cap,
-            profile=self._profile)
+            profile=self._profile,
+            device=None if shardings is not None else _placement(cl))
         rt.boot(self._state_factory(cl))
         return rt
 

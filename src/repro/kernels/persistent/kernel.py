@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.mailbox import (DESC_WIDTH, P_ACTIVE, P_OPCODE, P_QDEPTH,
                                 P_REQID, P_ROW, P_TICK0, P_TICK1, PROF_WIDTH,
@@ -163,125 +164,134 @@ def persistent_execute_pallas(queue, workspace, *, interpret: bool = False):
     return out, fromgpu
 
 
+def _store_row(ref, c, i, width: int, words: dict) -> None:
+    """Stamp one ``width``-word record of an SMEM ref word by word (SMEM
+    takes scalar stores only): ``words`` maps a word index to its value,
+    every other word is written 0 so no word of the output is left
+    undefined."""
+    for w in range(width):
+        ref[c, i, w] = words.get(w, jnp.int32(0))
+
+
 def _drain_body(ctrl_ref, queue_ref, out_ref, carry_out_ref, ack_ref,
                 res_ref, ctrl_out_ref, prof_ref=None, tick_out_ref=None):
     """Shared drain loop of the bare and profiled kernels (out_ref /
     carry_out_ref / tick_out_ref already hold their input copies).
+
+    Layout: the tile workspace ``out_ref`` is this cluster's VMEM block
+    ``(1, NBUF, T, T)``; every scalar word (control vector, descriptor
+    rows, ack rows, per-row results, carry, tick, profile rows) is a
+    whole-array SMEM ref indexed by the cluster ``c = program_id(0)``.
     When ``prof_ref`` is given, each row also stamps a flight-recorder
     profile record (``PROF_WIDTH`` words, see core.mailbox) and
     ``tick_out_ref`` advances the persistent logical-tick counter by one
     per executed row — the ack rows stay byte-identical either way."""
-    head = ctrl_ref[0, QC_HEAD]
-    tail = ctrl_ref[0, QC_TAIL]
-    stop = ctrl_ref[0, QC_STOP]
+    c = pl.program_id(0)
+    head = ctrl_ref[c, QC_HEAD]
+    tail = ctrl_ref[c, QC_TAIL]
+    stop = ctrl_ref[c, QC_STOP]
     q_len = queue_ref.shape[1]
 
-    def _dst_a(desc):
-        packed = desc[W_ARG0]
-        return packed // 256, packed % 256
+    def _dst_a(arg0):
+        # arg0 = dst*256 + a: floor-div/mod by 256 as shift/mask
+        return arg0 >> 8, arg0 & 255
 
-    def op_nop(i, desc):
-        res_ref[0, i, 0] = 0.0
+    def _write(i, dst, new):
+        out_ref[0, dst] = new
+        res_ref[c, i, 0] = jnp.sum(new)
 
-    def op_matmul(i, desc):
-        dst, a = _dst_a(desc)
-        b = desc[W_ARG1]
-        acc = jax.lax.dot_general(out_ref[0, a], out_ref[0, b],
+    def op_nop(i, arg0, arg1):
+        res_ref[c, i, 0] = jnp.float32(0.0)
+
+    def op_matmul(i, arg0, arg1):
+        dst, a = _dst_a(arg0)
+        acc = jax.lax.dot_general(out_ref[0, a], out_ref[0, arg1],
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        new = out_ref[0, dst] + acc
-        out_ref[0, dst] = new
-        res_ref[0, i, 0] = jnp.sum(new)
+        _write(i, dst, out_ref[0, dst] + acc)
 
-    def op_add(i, desc):
-        dst, a = _dst_a(desc)
-        new = out_ref[0, a] + out_ref[0, desc[W_ARG1]]
-        out_ref[0, dst] = new
-        res_ref[0, i, 0] = jnp.sum(new)
+    def op_add(i, arg0, arg1):
+        dst, a = _dst_a(arg0)
+        _write(i, dst, out_ref[0, a] + out_ref[0, arg1])
 
-    def op_scale(i, desc):
-        dst, a = _dst_a(desc)
-        scale = desc[W_ARG1].astype(jnp.float32) / (1 << SCALE_SHIFT)
-        new = out_ref[0, a] * scale
-        out_ref[0, dst] = new
-        res_ref[0, i, 0] = jnp.sum(new)
+    def op_scale(i, arg0, arg1):
+        dst, a = _dst_a(arg0)
+        scale = arg1.astype(jnp.float32) * (1.0 / (1 << SCALE_SHIFT))
+        _write(i, dst, out_ref[0, a] * scale)
 
-    def op_relu(i, desc):
-        dst, a = _dst_a(desc)
-        new = jnp.maximum(out_ref[0, a], 0.0)
-        out_ref[0, dst] = new
-        res_ref[0, i, 0] = jnp.sum(new)
+    def op_relu(i, arg0, arg1):
+        dst, a = _dst_a(arg0)
+        _write(i, dst, jnp.maximum(out_ref[0, a], 0.0))
 
-    def op_copy(i, desc):
-        dst, a = _dst_a(desc)
-        new = out_ref[0, a]
-        out_ref[0, dst] = new
-        res_ref[0, i, 0] = jnp.sum(new)
+    def op_copy(i, arg0, arg1):
+        dst, a = _dst_a(arg0)
+        _write(i, dst, out_ref[0, a])
 
-    def op_reduce(i, desc):
-        _dst, a = _dst_a(desc)
-        acc = carry_out_ref[0, 0] + jnp.sum(out_ref[0, a])
-        carry_out_ref[0, 0] = acc
-        res_ref[0, i, 0] = acc
+    def op_reduce(i, arg0, arg1):
+        _dst, a = _dst_a(arg0)
+        acc = carry_out_ref[c, 0] + jnp.sum(out_ref[0, a])
+        carry_out_ref[c, 0] = acc
+        res_ref[c, i, 0] = acc
 
     ops = [op_nop, op_matmul, op_add, op_scale, op_relu, op_copy,
            op_reduce]
 
     def body(i, drained):
-        desc = queue_ref[0, i]
+        status = queue_ref[c, i, W_STATUS]
+        opcode = queue_ref[c, i, W_OPCODE]
+        reqid = queue_ref[c, i, W_REQID]
+        chunk = queue_ref[c, i, W_CHUNK]
+        n_chunks = queue_ref[c, i, W_NCHUNKS]
         active = ((i >= head) & (i < tail) & (stop == 0)
-                  & (desc[W_STATUS] >= THREAD_WORK))
+                  & (status >= THREAD_WORK))
 
         def run():
-            opcode = jnp.clip(desc[W_OPCODE], 0, NUM_DRAIN_OPS - 1)
-            jax.lax.switch(opcode, ops, i, desc)
+            jax.lax.switch(jnp.clip(opcode, 0, NUM_DRAIN_OPS - 1), ops, i,
+                           queue_ref[c, i, W_ARG0], queue_ref[c, i, W_ARG1])
 
         def skip():
-            res_ref[0, i, 0] = 0.0
+            res_ref[c, i, 0] = jnp.float32(0.0)
 
         jax.lax.cond(active, run, skip)
         # the per-descriptor quantum: one chunk ran — FINISHED only when
         # it was the item's last, PREEMPTED otherwise (the host requeues
         # the remainder through the normal scheduling lane)
-        done = desc[W_CHUNK] + 1 >= jnp.maximum(desc[W_NCHUNKS], 1)
-        row = jnp.zeros((DESC_WIDTH,), jnp.int32)
-        row = row.at[W_STATUS].set(
-            jnp.where(active,
-                      jnp.where(done, THREAD_FINISHED, THREAD_PREEMPTED),
-                      THREAD_NOP))
-        row = row.at[W_REQID].set(desc[W_REQID])
-        row = row.at[W_CHUNK].set(desc[W_CHUNK])
-        row = row.at[W_NCHUNKS].set(desc[W_NCHUNKS])
-        ack_ref[0, i] = row
+        done = chunk + 1 >= jnp.maximum(n_chunks, 1)
+        _store_row(ack_ref, c, i, DESC_WIDTH, {
+            W_STATUS: jnp.where(
+                active, jnp.where(done, THREAD_FINISHED, THREAD_PREEMPTED),
+                THREAD_NOP).astype(jnp.int32),
+            W_REQID: reqid, W_CHUNK: chunk, W_NCHUNKS: n_chunks})
         act = active.astype(jnp.int32)
         if prof_ref is not None:
-            t0 = tick_out_ref[0, 0]
-            tick_out_ref[0, 0] = t0 + act
-            prow = jnp.zeros((PROF_WIDTH,), jnp.int32)
-            prow = prow.at[P_TICK0].set(act * t0)
-            prow = prow.at[P_TICK1].set(act * (t0 + 1))
-            prow = prow.at[P_ROW].set(act * drained)
-            # occupancy at pop: ring rows still pending, this one included
-            prow = prow.at[P_QDEPTH].set(act * (tail - i))
-            prow = prow.at[P_OPCODE].set(act * desc[W_OPCODE])
-            prow = prow.at[P_REQID].set(act * desc[W_REQID])
-            prow = prow.at[P_ACTIVE].set(act)
-            prof_ref[0, i] = prow
+            t0 = tick_out_ref[c, 0]
+            tick_out_ref[c, 0] = t0 + act
+            _store_row(prof_ref, c, i, PROF_WIDTH, {
+                P_TICK0: act * t0, P_TICK1: act * (t0 + 1),
+                P_ROW: act * drained,
+                # occupancy at pop: ring rows still pending, this one
+                # included
+                P_QDEPTH: act * (tail - i),
+                P_OPCODE: act * opcode, P_REQID: act * reqid,
+                P_ACTIVE: act})
         return drained + act
 
     drained = jax.lax.fori_loop(0, q_len, body, jnp.int32(0))
-    ctrl_out_ref[0, :] = ctrl_ref[0, :].at[QC_DRAINED].set(drained)
+    for w in range(QCTRL_WIDTH):
+        ctrl_out_ref[c, w] = drained if w == QC_DRAINED else ctrl_ref[c, w]
 
 
 def _drain_kernel(ctrl_ref, queue_ref, ws_ref, carry_ref, out_ref,
                   carry_out_ref, ack_ref, res_ref, ctrl_out_ref):
-    """ctrl: (1, QCTRL_WIDTH) i32; queue: (1, Q, DESC_WIDTH) i32;
-    ws/out: (1, NBUF, T, T) f32 (aliased); carry: (1, 1) f32 (aliased) —
-    the resumable reduction accumulator threaded across rows AND launches.
-    ack: (1, Q, DESC_WIDTH) i32 per-row from_gpu records; res: (1, Q, 1)
-    f32 per-row results; ctrl_out: ctrl with QC_DRAINED stamped."""
+    """SMEM: ctrl (C, QCTRL_WIDTH) i32; queue (C, Q, DESC_WIDTH) i32;
+    carry (C, 1) f32 (aliased) — the resumable reduction accumulator
+    threaded across rows AND launches; ack (C, Q, DESC_WIDTH) i32 per-row
+    from_gpu records; res (C, Q, 1) f32 per-row results; ctrl_out: ctrl
+    with QC_DRAINED stamped. VMEM: ws/out (1, NBUF, T, T) f32 (aliased),
+    this cluster's block."""
+    c = pl.program_id(0)
     out_ref[...] = ws_ref[...]
-    carry_out_ref[...] = carry_ref[...]
+    carry_out_ref[c, 0] = carry_ref[c, 0]
     _drain_body(ctrl_ref, queue_ref, out_ref, carry_out_ref, ack_ref,
                 res_ref, ctrl_out_ref)
 
@@ -290,13 +300,14 @@ def _drain_kernel_prof(ctrl_ref, queue_ref, ws_ref, carry_ref, tick_ref,
                        out_ref, carry_out_ref, ack_ref, res_ref,
                        ctrl_out_ref, prof_ref, tick_out_ref):
     """The flight-recorder variant of ``_drain_kernel``: same queue drain
-    and byte-identical ack rows, plus a ``(1, Q, PROF_WIDTH)`` profile
-    output and a persistent ``(1, 1)`` i32 logical-tick counter (aliased
-    input → output like the carry, so ticks stay monotone across
-    launches)."""
+    and byte-identical ack rows, plus a ``(C, Q, PROF_WIDTH)`` SMEM
+    profile output and a persistent ``(C, 1)`` i32 SMEM logical-tick
+    counter (aliased input → output like the carry, so ticks stay
+    monotone across launches)."""
+    c = pl.program_id(0)
     out_ref[...] = ws_ref[...]
-    carry_out_ref[...] = carry_ref[...]
-    tick_out_ref[...] = tick_ref[...]
+    carry_out_ref[c, 0] = carry_ref[c, 0]
+    tick_out_ref[c, 0] = tick_ref[c, 0]
     _drain_body(ctrl_ref, queue_ref, out_ref, carry_out_ref, ack_ref,
                 res_ref, ctrl_out_ref, prof_ref=prof_ref,
                 tick_out_ref=tick_out_ref)
@@ -313,6 +324,10 @@ def persistent_drain_pallas(ctrl, queue, workspace, carry, tick=None, *,
     Returns (workspace', carry', acks (C, Q, DESC_WIDTH),
     results (C, Q, 1), ctrl').
 
+    The grid walks the clusters in order; the workspace is blocked per
+    cluster in VMEM, and every scalar array is one whole-array SMEM
+    block that each grid step indexes by its cluster.
+
     With ``profile=True`` the flight-recorder kernel runs instead:
     ``tick`` (a (C, 1) i32 persistent logical-tick counter) is required,
     and the return gains ``(..., prof (C, Q, PROF_WIDTH), tick')`` —
@@ -322,64 +337,35 @@ def persistent_drain_pallas(ctrl, queue, workspace, carry, tick=None, *,
     assert W == DESC_WIDTH and T == TILE
     assert ctrl.shape == (C, QCTRL_WIDTH)
     assert carry.shape == (C, 1)
-
-    if not profile:
-        return pl.pallas_call(
-            _drain_kernel,
-            grid=(C,),
-            in_specs=[
-                pl.BlockSpec((1, QCTRL_WIDTH), lambda c: (c, 0)),
-                pl.BlockSpec((1, Q, W), lambda c: (c, 0, 0)),
-                pl.BlockSpec((1, NBUF, T, T), lambda c: (c, 0, 0, 0)),
-                pl.BlockSpec((1, 1), lambda c: (c, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, NBUF, T, T), lambda c: (c, 0, 0, 0)),
-                pl.BlockSpec((1, 1), lambda c: (c, 0)),
-                pl.BlockSpec((1, Q, W), lambda c: (c, 0, 0)),
-                pl.BlockSpec((1, Q, 1), lambda c: (c, 0, 0)),
-                pl.BlockSpec((1, QCTRL_WIDTH), lambda c: (c, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct(workspace.shape, workspace.dtype),
-                jax.ShapeDtypeStruct((C, 1), jnp.float32),
-                jax.ShapeDtypeStruct((C, Q, W), jnp.int32),
-                jax.ShapeDtypeStruct((C, Q, 1), jnp.float32),
-                jax.ShapeDtypeStruct((C, QCTRL_WIDTH), jnp.int32),
-            ],
-            input_output_aliases={2: 0, 3: 1},
-            interpret=interpret,
-        )(ctrl, queue, workspace, carry)
-
-    assert tick is not None and tick.shape == (C, 1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tiles = pl.BlockSpec((1, NBUF, T, T), lambda c: (c, 0, 0, 0))
+    in_specs = [smem, smem, tiles, smem]
+    out_specs = [tiles, smem, smem, smem, smem]
+    out_shape = [
+        jax.ShapeDtypeStruct(workspace.shape, workspace.dtype),
+        jax.ShapeDtypeStruct((C, 1), jnp.float32),
+        jax.ShapeDtypeStruct((C, Q, W), jnp.int32),
+        jax.ShapeDtypeStruct((C, Q, 1), jnp.float32),
+        jax.ShapeDtypeStruct((C, QCTRL_WIDTH), jnp.int32),
+    ]
+    args = (ctrl, queue, workspace, carry)
+    aliases = {2: 0, 3: 1}
+    kernel = _drain_kernel
+    if profile:
+        assert tick is not None and tick.shape == (C, 1)
+        in_specs.append(smem)
+        out_specs += [smem, smem]
+        out_shape += [jax.ShapeDtypeStruct((C, Q, PROF_WIDTH), jnp.int32),
+                      jax.ShapeDtypeStruct((C, 1), jnp.int32)]
+        args += (tick,)
+        aliases[4] = 6
+        kernel = _drain_kernel_prof
     return pl.pallas_call(
-        _drain_kernel_prof,
+        kernel,
         grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, QCTRL_WIDTH), lambda c: (c, 0)),
-            pl.BlockSpec((1, Q, W), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, NBUF, T, T), lambda c: (c, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, NBUF, T, T), lambda c: (c, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
-            pl.BlockSpec((1, Q, W), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, Q, 1), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, QCTRL_WIDTH), lambda c: (c, 0)),
-            pl.BlockSpec((1, Q, PROF_WIDTH), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(workspace.shape, workspace.dtype),
-            jax.ShapeDtypeStruct((C, 1), jnp.float32),
-            jax.ShapeDtypeStruct((C, Q, W), jnp.int32),
-            jax.ShapeDtypeStruct((C, Q, 1), jnp.float32),
-            jax.ShapeDtypeStruct((C, QCTRL_WIDTH), jnp.int32),
-            jax.ShapeDtypeStruct((C, Q, PROF_WIDTH), jnp.int32),
-            jax.ShapeDtypeStruct((C, 1), jnp.int32),
-        ],
-        input_output_aliases={2: 0, 3: 1, 4: 6},
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(ctrl, queue, workspace, carry, tick)
+    )(*args)

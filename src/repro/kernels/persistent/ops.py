@@ -13,6 +13,7 @@ import numpy as np
 from repro.core import mailbox as mb
 from repro.core.mailbox import (DESC_WIDTH, THREAD_NOP, THREAD_WORK, W_ARG0,
                                 W_ARG1, W_OPCODE, W_STATUS)
+from repro.kernels import default_interpret
 from repro.kernels.persistent import kernel as K
 
 
@@ -35,7 +36,7 @@ def build_queue(programs: list[list[tuple]], queue_len: int) -> np.ndarray:
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def persistent_execute(queue, workspace, *, interpret: bool | None = None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return K.persistent_execute_pallas(queue, workspace, interpret=interpret)
 
 
@@ -54,7 +55,7 @@ def persistent_drain(ctrl, queue, workspace, carry, *,
                      interpret: bool | None = None):
     """Jitted drain launch (``MegaRuntime``'s compiled fast path)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return K.persistent_drain_pallas(ctrl, queue, workspace, carry,
                                      interpret=interpret)
 
@@ -65,7 +66,7 @@ def persistent_drain_prof(ctrl, queue, workspace, carry, tick, *,
     """Jitted flight-recorder drain launch: the bare drain's outputs plus
     ``(prof, tick')`` profile rows (see ``core.mailbox`` PROF_* words)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return K.persistent_drain_pallas(ctrl, queue, workspace, carry, tick,
                                      profile=True, interpret=interpret)
 

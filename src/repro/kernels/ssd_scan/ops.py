@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd_scan.kernel import ssd_chunk_pallas
 
 
@@ -17,7 +18,7 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 64, interpret: bool | None = None):
     Returns (y (B,S,H,P), final state (B,H,P,N)).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     L = min(chunk, S)

@@ -7,20 +7,35 @@ WCET report (paper phases Init/Trigger/Wait/Dispose).
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core import wcet
+from repro.core.persistent import reap_deferred
 from repro.core.telemetry import TraceCollector
 from repro.core.wcet import WcetTracker
 from repro.distributed import ShardCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serving import ServingEngine
 
 
-def main(argv=None):
+@dataclass
+class ServeRun:
+    """What one serve run leaves behind: the live engine (not yet
+    drained or disposed), the model and weights it serves, the prompts
+    it was given, and each request's generated tokens."""
+    engine: ServingEngine
+    model: object
+    params: object
+    prompts: list
+    outs: list
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--reduced", action="store_true")
@@ -95,7 +110,23 @@ def main(argv=None):
         args.reduced = True
         args.requests = min(args.requests, 6)
         args.max_new = min(args.max_new, 4)
+    return args
 
+
+def main(argv=None):
+    run = serve(parse_args(argv))
+    # drain explicitly: a failure in the last steps raises here instead
+    # of being retired silently by dispose()
+    run.engine.dispatcher.drain()
+    run.engine.dispose()
+    reap_deferred()
+    return run.outs
+
+
+def serve(args: argparse.Namespace) -> ServeRun:
+    """Build the engine for ``args``, answer every request and print the
+    run's report; the engine is returned live."""
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -252,8 +283,7 @@ def main(argv=None):
         if args.metrics_file:
             print(f"[serve] metrics written to {args.metrics_file} "
                   f"(+ .prom sibling)")
-    engine.dispose()
-    return outs
+    return ServeRun(engine, model, params, prompts, outs)
 
 
 if __name__ == "__main__":

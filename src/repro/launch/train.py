@@ -21,6 +21,7 @@ from repro.core.wcet import WcetTracker
 from repro.data import DataConfig, ShardedLoader, SyntheticLM
 from repro.distributed import ShardCtx
 from repro.distributed.fault_tolerance import StragglerDetector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.optim.optimizer import cosine_schedule
@@ -44,6 +45,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

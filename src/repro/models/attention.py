@@ -29,19 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import Builder, softcap
 
-# jax >= 0.6 promotes shard_map to jax.shard_map and renames check_rep ->
-# check_vma; older releases ship it under jax.experimental
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _sm_legacy
-    _shard_map = functools.partial(_sm_legacy, check_rep=False)
-
-
-def _axis_size(ax):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -483,7 +471,7 @@ def decode_attention_sharded(q, k_cache, v_cache, valid_len, ctx, *,
         # global offset of this shard's cache slice
         idx = 0
         for ax in seq_axes:
-            idx = idx * _axis_size(ax) + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         offset = idx * S_loc
         kx, vx = ks, vs
         if G > 1:
@@ -544,7 +532,7 @@ def cache_update_sharded(k_cache, v_cache, k_new, v_new, positions, ctx):
         S_loc = kc.shape[1]
         idx = 0
         for ax in seq_axes:
-            idx = idx * _axis_size(ax) + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         offset = idx * S_loc
         local_pos = jnp.clip(pos - offset, 0, S_loc - 1)
         owns = (pos >= offset) & (pos < offset + S_loc)    # (B,)

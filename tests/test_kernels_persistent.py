@@ -241,3 +241,28 @@ def test_drain_random_programs_property(seed):
     out, ref, _ = drain_both(descs, head=head, tail=tail, seed=seed,
                              carry0=float(rng.uniform(-1, 1)))
     assert_drain_equal(out, ref)
+
+
+def test_drain_clusters_run_their_own_queues():
+    """A grid over several clusters: each cluster drains its own queue
+    and control window against its own workspace block and carry, exactly
+    as the oracle runs them one by one."""
+    rng = np.random.default_rng(7)
+    C, Q = 3, 8
+    ws = (rng.standard_normal((C, 4, TILE, TILE)) * 0.1).astype(np.float32)
+    rings, ctrls = [], []
+    for c in range(C):
+        descs = [mb.WorkDescriptor(opcode=int(rng.integers(0, NUM_DRAIN_OPS)),
+                                   arg0=pack_args(*(int(x) for x in
+                                                    rng.integers(0, 4, 2)))[0],
+                                   arg1=int(rng.integers(0, 4)),
+                                   request_id=10 * c + i, n_chunks=2)
+                 for i in range(2 + c)]
+        rings.append(mb.descriptor_ring(descs, Q))
+        ctrls.append(mb.queue_control(tail=len(descs), head=c % 2))
+    ring, ctrl = np.stack(rings), np.stack(ctrls)
+    carry = rng.uniform(-1, 1, (C, 1)).astype(np.float32)
+    out = persistent_drain(jnp.asarray(ctrl), jnp.asarray(ring),
+                           jnp.asarray(ws), jnp.asarray(carry),
+                           interpret=True)
+    assert_drain_equal(out, persistent_drain_ref(ctrl, ring, ws, carry))

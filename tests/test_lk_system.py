@@ -194,6 +194,42 @@ def test_self_healing_zero_lost_requests():
         assert s["n"] == 7 and s["heals"] == 1
 
 
+def test_failed_clusters_and_replays_are_counted():
+    """deadline_stats() counts every cluster _fail_cluster retired and
+    every item it replayed; a healthy run keeps both at zero."""
+    log = []
+    healthy = make_system(devices=devs(2), work_classes=[
+        WorkClass("w", fn=add_one)],
+        runtime_factory=lambda cl: FakeRuntime(cl.cid, log))
+    with healthy:
+        for _ in range(3):
+            healthy.submit("w")
+        healthy.drain()
+        s = healthy.stats()
+        assert (s["failed_clusters"], s["replayed"]) == (0, 0)
+
+    arm_fault = [True]
+
+    def factory(cl):
+        fail = arm_fault[0] and cl.cid == 0
+        return FakeRuntime(cl.cid, log, max_inflight=2, fail_wait=fail)
+
+    sys_ = make_system(devices=devs(9), n_clusters=2,
+                       runtime_factory=factory,
+                       work_classes=[WorkClass("w", fn=add_one, pin=0)])
+    with sys_:
+        arm_fault[0] = False
+        tickets = [sys_.submit("w") for _ in range(6)]
+        sys_.drain()
+        assert all(t.done() for t in tickets)
+        s = sys_.stats()
+        assert s["failed_clusters"] == 1 == sys_.heals
+        # the dead cluster held work in flight and queued: all of it
+        # was requeued onto live capacity
+        assert 1 <= s["replayed"] <= 6
+        assert sys_.dispatcher.counters()["dispatcher.failed_clusters"] == 1
+
+
 def test_displaced_survivor_lame_duck_reaped():
     """When the recarve rearranges the survivor's partition, the old
     runtime finishes its backlog as a lame duck and reap() retires it."""

@@ -28,7 +28,8 @@ from repro.models import build
 from repro.training import init_state, make_train_step, opt_config_for, state_shardings
 
 cfg = get_config("llama3-8b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 tokens = jax.random.randint(jax.random.key(1), (4, 32), 0, cfg.vocab_size)
 
 # single-device reference
@@ -61,7 +62,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed import ShardCtx
 from repro.models.attention import decode_attention_local, decode_attention_sharded, cache_update_sharded
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardCtx.for_mesh(mesh, "decode")
 rng = np.random.default_rng(0)
 B, S, Hq, Hkv, D = 4, 64, 8, 2, 16
@@ -149,3 +151,20 @@ for c in cm.clusters:
 assert outs == [8.0, 8.0]
 print("CLUSTER ISOLATION OK")
 """))
+
+
+def test_lk_system_clusters_on_their_own_devices():
+    """LkSystem with one cluster per device, under both runtimes: every
+    cluster's state sits on its own device, executables are compiled per
+    device, and every cluster's results equal a one-cluster run (the
+    four-chip phase of chip_smoke.py, on forced host devices)."""
+    out = run_snippet(r"""
+import sys
+sys.path.insert(0, %r)
+import jax
+import chip_smoke
+placed = chip_smoke.clusters_phase(jax.devices()[:4])
+assert placed["scan"] == placed["mega"] == [f"cpu:{i}" for i in range(4)], placed
+print("CLUSTERS OWN DEVICES OK", placed)
+""" % REPO)
+    assert "CLUSTERS OWN DEVICES OK" in out
