@@ -77,8 +77,9 @@ from repro.core.telemetry import (
     EV_REQUEUE, EV_RESOLVE, EV_SHED, EV_SUBMIT, EV_TRIGGER, TraceCollector,
 )
 # one clock stamps the whole timeline: dispatcher-side events and
-# collector-default-stamped events (heal, rt_*) must never drift apart
+# collector-default-stamped events (heal) must never drift apart
 from repro.core.telemetry.events import now_us
+from repro.core.wcet import WcetTracker
 
 __all__ = [
     "AdmissionError", "AllClustersFailed", "Completion", "Dispatcher",
@@ -244,7 +245,8 @@ class Dispatcher:
                  clock: Optional[Callable[[], int]] = None,
                  preemptive: Optional[bool] = None,
                  telemetry: Optional[TraceCollector] = None,
-                 wcet_quantile: Optional[float] = None):
+                 wcet_quantile: Optional[float] = None,
+                 tracker: Optional[WcetTracker] = None):
         for rt in runtimes.values():
             _require_runtime(rt)
         self.runtimes = dict(runtimes)
@@ -340,6 +342,9 @@ class Dispatcher:
         self.telemetry: Optional[TraceCollector] = None
         if telemetry is not None:
             self.attach_telemetry(telemetry)
+        # host phases of the pump (``dispatcher.pick``, ``.retire``):
+        # timed always, and spans of the profiler's trace while one runs
+        self.tracker = tracker or WcetTracker("dispatcher")
 
     # ------------------------------------------------------------------
     @property
@@ -711,10 +716,11 @@ class Dispatcher:
             return False
         if len(self._inflight[cluster]) >= rt.max_inflight:
             return False
-        item = self.policy.pop_next(cluster, self._clock())
-        if item is None:
-            return False              # deferred: budget exhausted
-        self._trigger_item(cluster, item)
+        with self.tracker.phase("dispatcher.pick", cluster=cluster):
+            item = self.policy.pop_next(cluster, self._clock())
+            if item is None:
+                return False          # deferred: budget exhausted
+            self._trigger_item(cluster, item)
         return True
 
     def _trigger_item(self, cluster: int, item: QueueItem) -> None:
@@ -812,6 +818,12 @@ class Dispatcher:
             return True
 
     def _retire(self, cluster: int) -> Optional[Completion]:
+        """``_retire_oldest`` inside the ``dispatcher.retire`` phase."""
+        rid = self._inflight[cluster][0][0].desc.request_id
+        with self.tracker.phase("dispatcher.retire", rid=rid):
+            return self._retire_oldest(cluster)
+
+    def _retire_oldest(self, cluster: int) -> Optional[Completion]:
         """Block on the cluster's OLDEST in-flight step; observe WCET,
         flag stragglers, ack the mailbox, charge the policy. A finished
         ITEM resolves its ticket and returns its Completion. A finished
@@ -1017,19 +1029,24 @@ class Dispatcher:
             while self._trigger_next(cluster):
                 n += 1
             return n
-        items = []
-        while self.policy.has_queued(cluster) and \
-                len(self._inflight[cluster]) + len(items) < rt.max_inflight:
-            item = self.policy.pop_next(cluster, self._clock())
-            if item is None:
-                break              # deferred: budget exhausted
-            items.append(item)
-        if not items:
+        if not self.policy.has_queued(cluster) or \
+                len(self._inflight[cluster]) >= rt.max_inflight:
             return 0
-        if len(items) == 1:
-            self._trigger_item(cluster, items[0])
-        else:
-            self._trigger_batch(cluster, items)
+        with self.tracker.phase("dispatcher.pick", cluster=cluster):
+            items = []
+            while self.policy.has_queued(cluster) and \
+                    len(self._inflight[cluster]) + len(items) \
+                    < rt.max_inflight:
+                item = self.policy.pop_next(cluster, self._clock())
+                if item is None:
+                    break          # deferred: budget exhausted
+                items.append(item)
+            if not items:
+                return 0
+            if len(items) == 1:
+                self._trigger_item(cluster, items[0])
+            else:
+                self._trigger_batch(cluster, items)
         return len(items)
 
     def poll(self) -> list[Completion]:

@@ -44,7 +44,7 @@ from repro.core import persistent as P
 from repro.core.persistent import (ExecutableCache, _Block,
                                    _device_key, _PipelinedRuntime,
                                    _tree_key)
-from repro.core.telemetry import EV_RT_TRIGGER, TraceCollector
+from repro.core.telemetry import TraceCollector
 from repro.core.telemetry.events import now_us
 from repro.core.wcet import WcetTracker
 from repro.kernels import default_interpret
@@ -113,7 +113,7 @@ class MegaRuntime(_PipelinedRuntime):
     def boot(self, state) -> None:
         """Init phase: compile the drain megakernel and make the tile
         workspace + reduce carry device-resident."""
-        with self.tracker.phase("init"):
+        with self.tracker.phase("init", "lk.init"):
             ws = jnp.asarray(state["ws"], jnp.float32)
             if ws.ndim != 3 or ws.shape[1:] != (K.TILE, K.TILE):
                 raise ValueError(
@@ -184,7 +184,11 @@ class MegaRuntime(_PipelinedRuntime):
             block = descs[base:base + self.max_steps]
             ring = mb.descriptor_ring(block, self.max_steps)
             ctrl = mb.queue_control(tail=len(block))
-            with self.tracker.phase("trigger"):
+            with self.tracker.phase("trigger", "lk.trigger",
+                                    batch=len(block),
+                                    depth=self.inflight + len(block)) as span:
+                if span is not None:
+                    span.update(P.PersistentRuntime.launch_args(block))
                 ctrl_dev, ring_dev = jax.device_put(
                     (ctrl[None], ring[None]), self.device)
                 prof = None
@@ -208,15 +212,6 @@ class MegaRuntime(_PipelinedRuntime):
             self.batched_steps += len(block)
             self.steps += len(block)
             self.tracker.record_depth(self.inflight)
-            if self.telemetry is not None:
-                # one batch-stamped event per drain launch — nothing is
-                # read back from the device on the trigger path
-                rid, opcode, chunk, _, _ = \
-                    P.PersistentRuntime._desc_fields(block[0])
-                self.telemetry.emit(
-                    EV_RT_TRIGGER, cluster=self.telemetry_cluster,
-                    request_id=rid, opcode=opcode, chunk=chunk,
-                    depth=self.inflight, batch=len(block))
         self.status = mb.THREAD_WORKING
         return len(descs)
 
@@ -237,7 +232,7 @@ class MegaRuntime(_PipelinedRuntime):
     def dispose(self) -> None:
         """Release device state — O(µs), blocking teardown deferred to
         ``reap_deferred()`` exactly like ``PersistentRuntime``."""
-        with self.tracker.phase("dispose"):
+        with self.tracker.phase("dispose", "lk.dispose"):
             held = (self._drain,)
             if self._inflight or self._ws is not None:
                 P._DEFERRED_TEARDOWN.append(

@@ -69,8 +69,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import mailbox as mb
-from repro.core.telemetry import (EV_CHUNK_RETIRE, EV_RT_RETIRE,
-                                  EV_RT_TRIGGER, TraceCollector)
+from repro.core import op_scopes
+from repro.core.telemetry import EV_CHUNK_RETIRE, TraceCollector
 from repro.core.telemetry.events import now_us
 from repro.core.wcet import WcetTracker
 
@@ -304,10 +304,9 @@ class _PipelinedRuntime:
         self._oldest_ready = False     # memoized ready() of the oldest block
         self.status = mb.THREAD_INIT
         self.steps = 0
-        # runtime-level telemetry: step enqueue/retire instants with the
-        # in-flight depth — the device-facing view of the same timeline
-        # the dispatcher annotates with scheduling decisions. The cluster
-        # id is assigned by whoever registers this runtime (LkSystem).
+        # the flight recorder's device spans go to this collector; the
+        # cluster id is assigned by whoever registers this runtime
+        # (LkSystem)
         self.telemetry = telemetry
         self.telemetry_cluster = -1
         # flight-recorder anchor: host end of the previously retired
@@ -392,7 +391,7 @@ class _PipelinedRuntime:
         (one readback); its remaining items then retire host-side."""
         assert self._inflight, "nothing in flight"
         blk = self._inflight[0]
-        with self.tracker.phase("wait"):
+        with self.tracker.phase("wait", "lk.wait") as span:
             first = blk.host_acks is None
             blk.materialize()
             if first:
@@ -402,15 +401,10 @@ class _PipelinedRuntime:
                 self._inflight.popleft()
                 self._oldest_ready = False
                 self._on_block_retired(blk)
+            if span is not None:
+                span["rid"] = int(from_gpu[mb.W_REQID])
         self.status = (mb.THREAD_WORKING if self._inflight
                        else int(from_gpu[mb.W_STATUS]))
-        if self.telemetry is not None:
-            self.telemetry.emit(
-                EV_RT_RETIRE, cluster=self.telemetry_cluster,
-                request_id=int(from_gpu[mb.W_REQID]),
-                chunk=int(from_gpu[mb.W_CHUNK]),
-                status=int(from_gpu[mb.W_STATUS]),
-                depth=self.inflight)
         return result, from_gpu
 
     def poll(self):
@@ -542,8 +536,13 @@ class PersistentRuntime(_PipelinedRuntime):
 
         def work_branch(state, carries, desc):
             def branch(i, fn):
+                # the work function's ops carry its scope in their HLO
+                # op_name (``core.op_scopes`` attributes them by it)
                 def run(state, carries, desc):
-                    state, carry, result, done = fn(state, carries[i], desc)
+                    with jax.named_scope(
+                            op_scopes.SCOPE_PREFIX + self.work_names[i]):
+                        state, carry, result, done = fn(
+                            state, carries[i], desc)
                     carries = tuple(carry if j == i else c
                                     for j, c in enumerate(carries))
                     return state, carries, result, jnp.asarray(done)
@@ -639,7 +638,7 @@ class PersistentRuntime(_PipelinedRuntime):
         was compiled before (same work fns / shapes / donate) skips the
         XLA compile entirely — the warm-reboot path of an elastic
         recarve."""
-        with self.tracker.phase("init"):
+        with self.tracker.phase("init", "lk.init"):
             if self._donate is None:
                 # donation serializes dispatch on CPU (module docstring):
                 # auto mode keeps the async Trigger/Wait split alive there
@@ -728,6 +727,15 @@ class PersistentRuntime(_PipelinedRuntime):
     def booted(self) -> bool:
         return self._compiled is not None
 
+    @classmethod
+    def launch_args(cls, descs) -> dict:
+        """The ``lk.trigger`` span's view of one launch: the opcode,
+        request id and chunk of each descriptor it carries."""
+        fields = [cls._desc_fields(d) for d in descs]
+        return {"ops": [f[1] for f in fields],
+                "rids": [f[0] for f in fields],
+                "chunks": [f[2] for f in fields]}
+
     @staticmethod
     def _desc_fields(desc) -> tuple:
         """(request_id, opcode, chunk, n_chunks, encoded) from either a
@@ -769,7 +777,9 @@ class PersistentRuntime(_PipelinedRuntime):
                 f"in-flight pipeline full (max_inflight={self.max_inflight});"
                 " retire with wait()/poll() first")
         rid, opcode, chunk, n_chunks, enc = self._desc_fields(desc)
-        with self.tracker.phase("trigger"):
+        with self.tracker.phase("trigger", "lk.trigger", ops=opcode,
+                                rids=rid, chunks=chunk, batch=1,
+                                depth=self.inflight + 1):
             dvec = self._staged.pop((rid, chunk), None)
             if dvec is not None:
                 self.staged_hits += 1          # device-resident re-trigger
@@ -797,11 +807,6 @@ class PersistentRuntime(_PipelinedRuntime):
                                          prof=prof,
                                          t_trigger_us=now_us()))
         self.tracker.record_depth(self.inflight)
-        if self.telemetry is not None:
-            self.telemetry.emit(
-                EV_RT_TRIGGER, cluster=self.telemetry_cluster,
-                request_id=rid, opcode=opcode, chunk=chunk,
-                depth=self.inflight)
         self.status = mb.THREAD_WORKING
         self.steps += 1
 
@@ -825,7 +830,11 @@ class PersistentRuntime(_PipelinedRuntime):
         for base in range(0, len(descs), self.max_steps):
             block = descs[base:base + self.max_steps]
             ring = mb.descriptor_ring(block, self.max_steps)
-            with self.tracker.phase("trigger"):
+            with self.tracker.phase("trigger", "lk.trigger",
+                                    batch=len(block),
+                                    depth=self.inflight + len(block)) as span:
+                if span is not None:
+                    span.update(self.launch_args(block))
                 ring_dev = jax.device_put(ring, self.device)
                 profs = None
                 if self._profile:
@@ -844,14 +853,6 @@ class PersistentRuntime(_PipelinedRuntime):
             self.batched_steps += len(block)
             self.steps += len(block)
             self.tracker.record_depth(self.inflight)
-            if self.telemetry is not None:
-                # one batch-stamped event per doorbell — the hot path
-                # reads NOTHING back from the device for telemetry
-                rid, opcode, chunk, _, _ = self._desc_fields(block[0])
-                self.telemetry.emit(
-                    EV_RT_TRIGGER, cluster=self.telemetry_cluster,
-                    request_id=rid, opcode=opcode, chunk=chunk,
-                    depth=self.inflight, batch=len(block))
         self.status = mb.THREAD_WORKING
         return len(descs)
 
@@ -869,6 +870,26 @@ class PersistentRuntime(_PipelinedRuntime):
         return result, from_gpu
 
     # ------------------------------------------------------------------
+    def op_scopes(self) -> dict[str, dict[str, tuple[str, str]]]:
+        """The compiled step programs' ops by work function: for each HLO
+        module (``jit__lk_step``, ``jit__lk_multi_step`` once a doorbell
+        compiled it), each instruction's (``lk.op.<name>`` or "" for the
+        runtime, ``op_name``) by the rules of ``core.op_scopes``. A
+        profiler trace names device ops by module and instruction, so
+        this is how a trace's ops find their work function.
+
+        A program whose HLO carries no work scope is left out: the
+        persistent compilation cache keys programs without their
+        metadata, so an executable it returns may have been compiled
+        from code that had no scopes, and would read as all runtime."""
+        out = {}
+        for exe in (self._compiled, self._compiled_multi):
+            if exe is not None:
+                module, ops = op_scopes.attribute(exe.as_text())
+                if any(scope for scope, _ in ops.values()):
+                    out[module] = ops
+        return out
+
     @property
     def state(self):
         return self._state
@@ -896,7 +917,7 @@ class PersistentRuntime(_PipelinedRuntime):
         recarve's displaced runtimes stop serving in microseconds instead
         of milliseconds. Past ``_DEFERRED_CAP`` unreaped teardowns, the
         reap runs inline as a memory backstop."""
-        with self.tracker.phase("dispose"):
+        with self.tracker.phase("dispose", "lk.dispose"):
             held = (self._compiled, self._compiled_multi, self._advance)
             if self._inflight or self._state is not None \
                     or self._carries is not None \

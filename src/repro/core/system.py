@@ -543,8 +543,8 @@ class LkSystem:
             rt = self._make_runtime(cl)
         self.dispatcher.register(did, rt)
         if self.telemetry is not None and hasattr(rt, "telemetry_cluster"):
-            # runtime-level events carry the dispatcher cluster id so the
-            # rt_* instants line up with the dispatcher's spans
+            # the flight recorder's device spans carry the dispatcher
+            # cluster id so they line up with the dispatcher's spans
             rt.telemetry_cluster = did
         self._runtimes[did] = rt
         self._cluster_of[did] = cl

@@ -31,9 +31,8 @@ traced workload end to end.
 """
 from repro.core.telemetry.events import (
     EV_ADMIT, EV_CANCEL, EV_CHUNK_RETIRE, EV_ENGINE, EV_FAIL, EV_HEAL,
-    EV_PREEMPT, EV_RECARVE, EV_REJECT, EV_REQUEUE, EV_RESOLVE,
-    EV_RT_RETIRE, EV_RT_TRIGGER, EV_SHED, EV_STREAM, EV_SUBMIT, EV_TRIGGER,
-    EVENT_KINDS, Event, TraceCollector,
+    EV_PREEMPT, EV_RECARVE, EV_REJECT, EV_REQUEUE, EV_RESOLVE, EV_SHED,
+    EV_STREAM, EV_SUBMIT, EV_TRIGGER, EVENT_KINDS, Event, TraceCollector,
 )
 from repro.core.telemetry.export import (
     DEVICE_PID_BASE, chrome_trace, write_chrome, write_csv,
@@ -52,7 +51,7 @@ __all__ = [
     "EV_ADMIT", "EV_CANCEL", "EV_CHUNK_RETIRE", "EV_ENGINE", "EV_FAIL",
     "EV_HEAL", "EV_PREEMPT", "EV_RECARVE", "EV_REJECT", "EV_REQUEUE",
     "EV_RESOLVE",
-    "EV_RT_RETIRE", "EV_RT_TRIGGER", "EV_SHED", "EV_STREAM", "EV_SUBMIT",
+    "EV_SHED", "EV_STREAM", "EV_SUBMIT",
     "EV_TRIGGER",
     "Event", "Gauge", "Histogram", "LogHistogram", "MetricsPump",
     "MetricsRegistry", "TraceCollector", "Violation", "WCET_OVERRUN",

@@ -38,7 +38,7 @@ __all__ = ["Event", "TraceCollector", "EVENT_KINDS",
            "EV_SUBMIT", "EV_ADMIT", "EV_REJECT", "EV_SHED", "EV_TRIGGER",
            "EV_CHUNK_RETIRE", "EV_PREEMPT", "EV_REQUEUE", "EV_RESOLVE",
            "EV_CANCEL", "EV_FAIL", "EV_HEAL", "EV_RECARVE",
-           "EV_RT_TRIGGER", "EV_RT_RETIRE", "EV_ENGINE", "EV_STREAM"]
+           "EV_ENGINE", "EV_STREAM"]
 
 # -- event kinds (the wire vocabulary of the timeline) ---------------------
 EV_SUBMIT = "submit"            # a descriptor entered a policy queue
@@ -56,8 +56,6 @@ EV_HEAL = "heal"                # LkSystem rebuilt capacity after a failure
 EV_RECARVE = "recarve"          # elastic repartition: proposed carve applied
 #                                 (or rejected=True when the admission
 #                                 re-check refused it)
-EV_RT_TRIGGER = "rt_trigger"    # runtime-level: step enqueued (depth sample)
-EV_RT_RETIRE = "rt_retire"      # runtime-level: oldest step retired
 EV_ENGINE = "engine"            # serving-engine lifecycle (add_request, …)
 EV_STREAM = "stream"            # request-stream lifecycle (open/slot-bind/
 #                                 prefill-chunk/first-token/decode/shed/close)
@@ -65,7 +63,7 @@ EV_STREAM = "stream"            # request-stream lifecycle (open/slot-bind/
 EVENT_KINDS = (
     EV_SUBMIT, EV_ADMIT, EV_REJECT, EV_SHED, EV_TRIGGER, EV_CHUNK_RETIRE,
     EV_PREEMPT, EV_REQUEUE, EV_RESOLVE, EV_CANCEL, EV_FAIL, EV_HEAL,
-    EV_RECARVE, EV_RT_TRIGGER, EV_RT_RETIRE, EV_ENGINE, EV_STREAM,
+    EV_RECARVE, EV_ENGINE, EV_STREAM,
 )
 
 

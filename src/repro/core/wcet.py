@@ -4,6 +4,11 @@ Phases mirror the paper: LK Init / Trigger / Wait / Dispose (and the
 traditional-path Alloc / Spawn / Wait / Dispose). We record wall-clock ns per
 phase and report average, worst, variance — the paper's predictability metric
 is exactly the avg↔worst gap.
+
+While a ``jax.profiler`` trace is active, each phase is also a span in it
+(``TraceAnnotation``), on the trace's own clock and carrying the phase's
+arguments as metadata, so the host side of the serve path lines up with the
+device operations it launched. With no trace active the span is skipped.
 """
 from __future__ import annotations
 
@@ -12,6 +17,9 @@ import time
 from collections import defaultdict
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from typing import Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 PHASES = ("init", "trigger", "wait", "dispose")
 
@@ -58,6 +66,15 @@ class PhaseStats:
                 "std_ns": self.std_ns}
 
 
+def _span_value(v):
+    """A span argument as the profiler stores it: numbers and strings as
+    they are, a sequence as its items joined by spaces (the trace's
+    metadata encoding reserves commas)."""
+    if isinstance(v, (list, tuple)):
+        return " ".join(str(x) for x in v)
+    return v
+
+
 class WcetTracker:
     """Per-phase timing aggregator with a context-manager interface."""
 
@@ -66,11 +83,30 @@ class WcetTracker:
         self.stats: dict[str, PhaseStats] = defaultdict(PhaseStats)
 
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, span: Optional[str] = None,
+              **args) -> Iterator[Optional[dict]]:
+        """Time the enclosed block as phase ``name``. Inside a profiler
+        trace the block is also a span named ``span`` (default ``name``)
+        with ``args`` as its metadata; the context then yields a dict
+        whose entries join those args at the span's end (what is known
+        only afterwards). With no trace active it yields None, so callers
+        build costly arguments only when someone is tracing."""
         t0 = time.perf_counter_ns()
+        ann = late = None
+        if TraceAnnotation.is_enabled():
+            ann = TraceAnnotation(span or name,
+                                  **{k: _span_value(v)
+                                     for k, v in args.items()})
+            ann.__enter__()
+            late = {}
         try:
-            yield
+            yield late
         finally:
+            if ann is not None:
+                if late:
+                    ann.set_metadata(**{k: _span_value(v)
+                                        for k, v in late.items()})
+                ann.__exit__(None, None, None)
             self.stats[name].record(time.perf_counter_ns() - t0)
 
     def record(self, name: str, ns: float) -> None:

@@ -244,8 +244,9 @@ def build(cfg: ModelConfig, ctx: ShardCtx | None = None) -> Model:
                         valid_len=valid_len)
         x, _, new_caches = out
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.tie_embeddings,
-                         cfg.logit_softcap, ctx)
+        with jax.named_scope("lm_head"):
+            logits = unembed(params["embed"], x, cfg.tie_embeddings,
+                             cfg.logit_softcap, ctx)
         return logits, new_caches
 
     # -- caches ----------------------------------------------------------------

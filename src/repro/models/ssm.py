@@ -206,24 +206,29 @@ def _conv_step(cache, xt, w):
 def ssm_block_decode(p, u, state, cfg, ctx):
     """u: (B,1,d) single token. Returns (out (B,1,d), new state)."""
     s = cfg.ssm
-    z, x, Bm, Cm, dt = _project(p, u, ctx)
-    x1, cx = _conv_step(state["conv_x"], x[:, 0], p["conv_x"])
-    B1, cB = _conv_step(state["conv_B"], Bm[:, 0], p["conv_B"])
-    C1, cC = _conv_step(state["conv_C"], Cm[:, 0], p["conv_C"])
-    x1, B1, C1 = jax.nn.silu(x1), jax.nn.silu(B1), jax.nn.silu(C1)
+    # named scopes: a profiler trace's ops name the block's part they ran
+    with jax.named_scope("mamba2.in_proj"):
+        z, x, Bm, Cm, dt = _project(p, u, ctx)
+    with jax.named_scope("mamba2.conv"):
+        x1, cx = _conv_step(state["conv_x"], x[:, 0], p["conv_x"])
+        B1, cB = _conv_step(state["conv_B"], Bm[:, 0], p["conv_B"])
+        C1, cC = _conv_step(state["conv_C"], Cm[:, 0], p["conv_C"])
+        x1, B1, C1 = jax.nn.silu(x1), jax.nn.silu(B1), jax.nn.silu(C1)
 
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    dt1 = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
-                          + p["dt_bias"].astype(jnp.float32))
-    dt1 = jnp.clip(dt1, s.dt_min, s.dt_max)                 # (B,H)
-    decay = jnp.exp(dt1 * A)                                # (B,H)
-    st = state["ssd"]
-    st = (st * decay[:, :, None, None]
-          + jnp.einsum("bh,bhp,bn->bhpn", dt1, x1, B1))
-    y = jnp.einsum("bn,bhpn->bhp", C1, st)
-    y = y + p["D"].astype(jnp.float32)[None, :, None] * x1
-    y = (y.astype(u.dtype) * jax.nn.silu(z[:, 0]))
-    y = rms_norm(y, p["gate_norm"], cfg.norm_eps)
-    out = jnp.einsum("bhp,hpd->bd", y, p["w_out"])[:, None]
+    with jax.named_scope("mamba2.ssd"):
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        dt1 = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
+                              + p["dt_bias"].astype(jnp.float32))
+        dt1 = jnp.clip(dt1, s.dt_min, s.dt_max)             # (B,H)
+        decay = jnp.exp(dt1 * A)                            # (B,H)
+        st = state["ssd"]
+        st = (st * decay[:, :, None, None]
+              + jnp.einsum("bh,bhp,bn->bhpn", dt1, x1, B1))
+        y = jnp.einsum("bn,bhpn->bhp", C1, st)
+        y = y + p["D"].astype(jnp.float32)[None, :, None] * x1
+    with jax.named_scope("mamba2.out_proj"):
+        y = (y.astype(u.dtype) * jax.nn.silu(z[:, 0]))
+        y = rms_norm(y, p["gate_norm"], cfg.norm_eps)
+        out = jnp.einsum("bhp,hpd->bd", y, p["w_out"])[:, None]
     new_state = {"ssd": st, "conv_x": cx, "conv_B": cB, "conv_C": cC}
     return ctx.constrain(out, "act_batch", "act_seq", "act_embed"), new_state

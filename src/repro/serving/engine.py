@@ -50,7 +50,9 @@ freeing host-side immediately).
 
 Phases feed the WcetTracker: Init = boot/compile, Trigger = descriptor
 dispatch, Wait = block_until_ready — directly comparable to paper Tables
-II/III via benchmarks/bench_dispatch.py.
+II/III via benchmarks/bench_dispatch.py. The engine-owned dispatcher and a
+stream frontend time their phases (pick, retire, poll, admit, harvest) in
+the same tracker, and every phase is a span of a running profiler trace.
 """
 from __future__ import annotations
 
@@ -297,7 +299,7 @@ class ServingEngine:
                 completion_window=completion_window
                 if completion_window is not None else 1024,
                 policy=policy, classes=class_specs,
-                telemetry=telemetry)
+                telemetry=telemetry, tracker=self.tracker)
         else:
             # raises if cluster_id is taken — silently adopting another
             # engine's runtime would decode against the wrong state
@@ -491,19 +493,23 @@ class ServingEngine:
         self._step_counter += 1
         ticket = self.dispatcher.submit(desc, cluster=self.cluster,
                                         admission=False)
-        toks = np.asarray(ticket.result())
-        out = {}
-        for i in self.slots.decoding_indices():
-            s = self.slots.slots[i]
-            t = int(toks[i])
-            s.generated.append(t)
-            s.length += 1
-            out[i] = t
-            if t == self.eos_id or s.length >= s.max_len:
-                if auto_free:
-                    self.slots.free(i)
-                else:
-                    s.phase = PH_FINISHED
+        result = ticket.result()
+        with self.tracker.phase("streams.harvest") as span:
+            toks = np.asarray(result)
+            out = {}
+            for i in self.slots.decoding_indices():
+                s = self.slots.slots[i]
+                t = int(toks[i])
+                s.generated.append(t)
+                s.length += 1
+                out[i] = t
+                if t == self.eos_id or s.length >= s.max_len:
+                    if auto_free:
+                        self.slots.free(i)
+                    else:
+                        s.phase = PH_FINISHED
+            if span is not None:
+                span["tokens"] = len(out)
         return out
 
     # ------------------------------------------------------------------

@@ -100,6 +100,7 @@ class StreamRequest:
     demand_us: float = 0.0        # exclusive demand charged at admission
     deadline_us: int = 0          # admitted response-time bound (absolute)
     opened_us: int = 0
+    arrival_us: int = 0           # request arrival (TTFT counts from it)
     admitted_us: int = 0
     first_token_us: int = 0
     closed_us: int = 0
@@ -194,9 +195,14 @@ class StreamFrontend:
 
     # -- public API ------------------------------------------------------
     def open_stream(self, prompt, max_new_tokens: int = 16,
-                    criticality: str = CRIT_LOW) -> int:
+                    criticality: str = CRIT_LOW,
+                    arrival_us: Optional[int] = None) -> int:
         """Register one request stream; returns its stream id. Admission
-        (slot binding + prefill submission) happens inside ``poll``."""
+        (slot binding + prefill submission) happens inside ``poll``.
+        ``arrival_us`` (``now_us`` clock, default now) is when the request
+        reached the server: TTFT (``stream_ttft_us`` and the first-token
+        event) counts from it, so queueing before this call is part of
+        it. No deadline, bound or admission decision reads it."""
         if criticality not in (CRIT_HIGH, CRIT_LOW):
             raise ValueError(f"unknown criticality {criticality!r}")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -208,9 +214,12 @@ class StreamFrontend:
                 f"exceeds max_seq({self.engine.max_seq})")
         sid = self._next_stream
         self._next_stream += 1
+        opened = now_us()
         st = StreamRequest(stream_id=sid, prompt=prompt,
                            max_new_tokens=int(max_new_tokens),
-                           criticality=criticality, opened_us=now_us())
+                           criticality=criticality, opened_us=opened,
+                           arrival_us=opened if arrival_us is None
+                           else int(arrival_us))
         self.streams[sid] = st
         self._pending.append(sid)
         self.opened += 1
@@ -218,7 +227,7 @@ class StreamFrontend:
             EV_STREAM, cluster=self.engine.cluster, request_id=sid,
             opcode=st.opcode, phase="open", criticality=criticality,
             prompt_tokens=int(prompt.shape[0]),
-            max_new_tokens=st.max_new_tokens)
+            max_new_tokens=st.max_new_tokens, arrival_us=st.arrival_us)
         return sid
 
     @property
@@ -296,6 +305,14 @@ class StreamFrontend:
             return False
         rid = self._next_work_rid
         self._next_work_rid += 1
+        with self.engine.tracker.phase("streams.admit", sid=st.stream_id,
+                                       rid=rid):
+            return self._bind(st, rid, now, demand, deadline)
+
+    def _bind(self, st: StreamRequest, rid: int, now: int, demand: float,
+              deadline: int) -> bool:
+        """Slot bind, ``add_request`` (prompt staging, prefill submit)
+        and the stream's promise, for an admitted stream."""
         slot = self.engine.add_request(rid, st.prompt, st.max_new_tokens)
         if slot is None:            # raced: treat as no-slot
             return False
@@ -438,14 +455,24 @@ class StreamFrontend:
                                   if s not in admitted)
 
     def _poll_transitions(self) -> None:
+        with self.engine.tracker.phase("streams.harvest") as span:
+            n = self._harvest()
+            if span is not None:
+                span["tokens"] = n
+
+    def _harvest(self) -> int:
+        """Walk every slot-holding stream's transitions (first token,
+        decoded tokens, close); returns the tokens harvested."""
         now = now_us()
+        n = 0
         for st in list(self._by_slot.values()):
             if st.state == ST_PREFILL and st.slot_obj.phase in (
                     PH_DECODING, PH_FINISHED):
                 st.state = ST_DECODING
                 st.first_token_us = now
                 st.tokens = list(st.slot_obj.generated)
-                ttft = now - st.opened_us
+                n += len(st.tokens)
+                ttft = now - st.arrival_us
                 self.collector.observe("stream_ttft_us", st.opcode,
                                        float(ttft))
                 self.collector.emit(
@@ -460,9 +487,11 @@ class StreamFrontend:
                         request_id=st.stream_id, opcode=st.opcode,
                         phase="decode", slot=st.slot, token=int(tok))
                 st.tokens.extend(int(t) for t in new)
+                n += len(new)
                 if st.slot_obj.phase == PH_FINISHED or \
                         len(st.tokens) >= st.max_new_tokens:
                     self._close(st, now)
+        return n
 
     def _close(self, st: StreamRequest, now: int) -> None:
         st.state = ST_CLOSED
@@ -486,22 +515,25 @@ class StreamFrontend:
         """One serve iteration: flush deferred sheds, admit pending
         streams, run one decode step (or drive queued prefill work when
         nothing is decoding yet), then harvest stream transitions."""
-        self._flush_deferred_sheds()
-        self._admit_pending()
-        if self.engine.slots.decoding_indices():
-            # the decode step carries a REAL deadline so EDF lets it cut
-            # in ahead of deadline-free prefill chunks — this is the
-            # decode/prefill interleave
-            step_us, _, chunk_us = self._estimates()
-            deadline = int(now_us() + self.decode_deadline_factor
-                           * (step_us + chunk_us) + self.slack_us)
-            self.engine.step(deadline_us=deadline, auto_free=False)
-        elif self.dispatcher.queue_depth(self.engine.cluster) or \
-                self.dispatcher.inflight_depth(self.engine.cluster):
-            # nothing decoding yet: drive prefill/insert/release work so
-            # first inserts can land
-            self.dispatcher.pump(self.engine.cluster)
-        self._poll_transitions()
+        with self.engine.tracker.phase("streams.poll",
+                                       live=len(self._by_slot),
+                                       pending=len(self._pending)):
+            self._flush_deferred_sheds()
+            self._admit_pending()
+            if self.engine.slots.decoding_indices():
+                # the decode step carries a REAL deadline so EDF lets it
+                # cut in ahead of deadline-free prefill chunks — this is
+                # the decode/prefill interleave
+                step_us, _, chunk_us = self._estimates()
+                deadline = int(now_us() + self.decode_deadline_factor
+                               * (step_us + chunk_us) + self.slack_us)
+                self.engine.step(deadline_us=deadline, auto_free=False)
+            elif self.dispatcher.queue_depth(self.engine.cluster) or \
+                    self.dispatcher.inflight_depth(self.engine.cluster):
+                # nothing decoding yet: drive prefill/insert/release work
+                # so first inserts can land
+                self.dispatcher.pump(self.engine.cluster)
+            self._poll_transitions()
 
     def serve(self, max_polls: int = 1_000_000) -> None:
         """Poll until every opened stream has closed."""

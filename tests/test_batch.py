@@ -2,19 +2,20 @@
 triggers, the multi-step loop's token identity for chunked carries,
 mid-batch preempted chunks, dispatcher coalescing, failure replay of an
 un-acked batch suffix, the staged double buffer, and batch-stamped
-telemetry."""
+telemetry and trace spans."""
+import glob
 from collections import deque
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core import mailbox as mb
 from repro.core.dispatcher import Dispatcher
 from repro.core.persistent import PersistentRuntime
-from repro.core.telemetry import (
-    EV_RT_TRIGGER, EV_TRIGGER, TraceCollector,
-)
+from repro.core.telemetry import EV_TRIGGER, TraceCollector
 
 
 def add_fn(state, desc):
@@ -277,15 +278,22 @@ def test_dispatcher_surfaces_staged_counters():
     rt.dispose()
 
 
-def test_batch_stamped_rt_trigger_event():
-    tel = TraceCollector()
-    rt = make_rt(telemetry=tel)
-    rt.trigger_many([mb.WorkDescriptor(opcode=0, arg0=1, request_id=i)
-                     for i in range(3)])
-    rt.wait_all()
-    evs = tel.events_of(EV_RT_TRIGGER)
-    assert len(evs) == 1            # ONE doorbell event for the batch
-    assert evs[0].extra["batch"] == 3
+def test_batch_stamped_rt_trigger_event(tmp_path):
+    """A three-descriptor doorbell is ONE ``lk.trigger`` span in the
+    profiler's trace, stamped with the batch and each descriptor."""
+    rt = make_rt()
+    rt._ensure_multi()
+    with jax.profiler.trace(str(tmp_path)):
+        rt.trigger_many([mb.WorkDescriptor(opcode=0, arg0=1, request_id=i)
+                         for i in range(3)])
+        rt.wait_all()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [dict(e.stats) for plane in ProfileData.from_file(path[0]).planes
+             for line in plane.lines for e in line.events
+             if e.name == "lk.trigger"]
+    assert len(spans) == 1          # ONE doorbell span for the batch
+    assert spans[0]["batch"] == 3 and spans[0]["depth"] == 3
+    assert spans[0]["rids"] == "0 1 2" and spans[0]["ops"] == "0 0 0"
     rt.dispose()
 
 
