@@ -372,7 +372,9 @@ class _PipelinedRuntime:
         scale = (end - start) / max(float(t1s.max()) - lo, 1.0)
         for j, i in enumerate(idxs):
             s = float(start + (t0s[j] - lo) * scale)
-            d = float(max((t1s[j] - t0s[j]) * scale, 1.0))
+            # the span's end is mapped like the next span's start, so
+            # spans that abut in ticks abut exactly in microseconds
+            d = float(max(start + (t1s[j] - lo) * scale - s, 1.0))
             self.telemetry.emit(
                 EV_CHUNK_RETIRE, cluster=self.telemetry_cluster,
                 request_id=int(prof[i, mb.P_REQID]),

@@ -194,30 +194,49 @@ def forward_stack(params, x, cfg, ctx, *, mode: str, pos,
     aux0 = {k: jnp.zeros((), jnp.float32) for k in aux_keys}
     unroll = num_periods(cfg) if cfg.scan_unroll else 1
     if mode == "decode":
-        # caches ride in the CARRY with per-layer dynamic in-place slice
-        # updates — passing them through scan xs/ys makes XLA materialize
-        # full-cache copies (measured: 32.6 GiB temp on qwen2-72b
-        # decode_32k via xs/ys vs O(1 layer slice) carried)
+        # Two cache routes, chosen by layer kind:
+        # - attention k/v ride in the CARRY with per-layer dynamic in-place
+        #   slice updates: a step writes one position of a large cache, and
+        #   passing them through scan xs/ys makes XLA materialize
+        #   full-cache copies (measured: 32.6 GiB temp on qwen2-72b
+        #   decode_32k via xs/ys vs O(1 layer slice) carried)
+        # - SSM state rides as per-layer xs/ys: a step rewrites every
+        #   element of it, so reading the stack once and writing it once is
+        #   its floor; carried, XLA copied the whole stacked state twice
+        #   per layer (in and out of the in-place update)
+        streamed = [f"blk{i}" for i, (kind, _) in enumerate(spec)
+                    if kind == "ssm"]
+        carried = {k: c for k, c in caches.items() if k not in streamed}
+
         def dbody(carry, xs):
             x, aux_acc, cc = carry
-            lp, li = xs
+            lp, li, sc = xs
             cc = dict(cc)
+            new_sc = {}
             for i, (kind, opts) in enumerate(spec):
-                cache_i = jax.tree.map(
-                    lambda c: jax.lax.dynamic_index_in_dim(
-                        c, li, 0, keepdims=False), cc[f"blk{i}"])
+                key = f"blk{i}"
+                if key in sc:
+                    cache_i = sc[key]
+                else:
+                    cache_i = jax.tree.map(
+                        lambda c: jax.lax.dynamic_index_in_dim(
+                            c, li, 0, keepdims=False), cc[key])
                 x, aux, nc = layer_apply(
-                    lp[f"blk{i}"], x, cfg, ctx, kind, opts, mode=mode,
+                    lp[key], x, cfg, ctx, kind, opts, mode=mode,
                     pos=pos, cache=cache_i, valid_len=valid_len)
-                cc[f"blk{i}"] = jax.tree.map(
-                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                        c, n.astype(c.dtype), li, 0), cc[f"blk{i}"], nc)
-            return (x, aux_acc, cc), None
+                if key in sc:
+                    new_sc[key] = nc
+                else:
+                    cc[key] = jax.tree.map(
+                        lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                            c, n.astype(c.dtype), li, 0), cc[key], nc)
+            return (x, aux_acc, cc), new_sc
 
         idxs = jnp.arange(num_periods(cfg))
-        (x, aux, new_caches), _ = jax.lax.scan(
-            dbody, (x, aux0, caches), (params, idxs), unroll=unroll)
-        return x, aux, new_caches
+        (x, aux, new_caches), new_streamed = jax.lax.scan(
+            dbody, (x, aux0, carried),
+            (params, idxs, {k: caches[k] for k in streamed}), unroll=unroll)
+        return x, aux, {**new_caches, **new_streamed}
     (x, aux), ys = jax.lax.scan(body, (x, aux0), (params,), unroll=unroll)
     if mode == "prefill":
         new_caches = {f"blk{i}": ys[i] for i in range(len(spec))}

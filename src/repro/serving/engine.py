@@ -241,7 +241,17 @@ class ServingEngine:
                     jnp.reshape(pos, (1,)))
                 return caches, jnp.argmax(logits[0, 0]).astype(jnp.int32)
 
-            caches, last = jax.lax.fori_loop(0, n, body, (caches0, last0))
+            # two tokens per iteration: the layer scan returns the SSM
+            # state in a fresh buffer while it still reads the old one, so
+            # a one-token loop copies the whole stack back into its carry
+            # every token; the second token's state takes the first one's
+            # dead input buffer, and the loop carries it with no copy
+            def pair(j, acc):
+                return body(2 * j + 1, body(2 * j, acc))
+
+            acc = jax.lax.fori_loop(0, n // 2, pair, (caches0, last0))
+            caches, last = jax.lax.cond(
+                n % 2 == 1, lambda a: body(n - 1, a), lambda a: a, acc)
             done = chunk + 1 >= desc[mb.W_NCHUNKS]
             new_caches = insert_slot_caches(stg["caches"], caches, slot)
             token = jax.lax.dynamic_update_slice(

@@ -8,18 +8,24 @@ running this file loads the TPU compiler. The persistent compilation cache
 is off around these compiles: an entry written for a described chip cannot
 be read back without one.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core import mailbox as mb
+from repro.core import op_scopes
 from repro.core.persistent import PersistentRuntime
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.persistent import kernel as K
+from repro.models import build
+from repro.serving import ServingEngine
 
 
 @pytest.fixture(scope="module")
@@ -91,3 +97,74 @@ def test_scan_multi_step_compiles(one_chip):
         state, carries, ring).compile()
     # the donated state is updated in place
     assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = op_scopes._COMPUTATION.match(line)
+        if m and not line.startswith(" "):
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _reached(comps: dict[str, list[str]], root: str) -> set[str]:
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps.get(name, ()):
+            for ref in op_scopes._CALLS.findall(line):
+                todo += op_scopes._names(ref)
+    return seen
+
+
+def _loop_copies(hlo: str, scope: str, shape: str) -> dict[str, int]:
+    """{loop op_name: copies of ``shape`` in its body} for every while
+    loop whose op_name holds ``scope``, the loops it calls included."""
+    comps = _computations(hlo)
+    copy = re.compile(r"=\s*" + re.escape(shape) + r"\{[^}]*\}\s+copy\(")
+    out = {}
+    for lines in comps.values():
+        for line in lines:
+            op = re.search(r'op_name="([^"]*)"', line)
+            if " while(" not in line or not op or scope not in op.group(1):
+                continue
+            body = re.search(r"\bbody=%?([\w.\-]+)", line).group(1)
+            out[op.group(1)] = sum(
+                bool(copy.search(ln)) for c in _reached(comps, body)
+                for ln in comps.get(c, ()))
+    return out
+
+
+def test_decode_layer_loop_does_not_copy_the_ssm_state(one_chip):
+    """The serving step program of a 2-layer, 8-slot mamba2-780m as the
+    runtime compiles it, state donated: no loop of the decode or prefill
+    work function copies the stacked SSD state (a carried stack was
+    copied in and out of the decode layer loop on every layer)."""
+    cfg = dataclasses.replace(get_config("mamba2-780m"), num_layers=2)
+    model = build(cfg)
+    params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                          jax.eval_shape(model.init, jax.random.key(0)))
+    engine = ServingEngine(model, params, max_batch=8, max_seq=256,
+                           chunked_prefill=True)
+    rt = engine.rt
+    state, carries = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), (rt.state, rt._carries))
+    desc = _sds(one_chip, (mb.DESC_WIDTH,), jnp.int32)
+    hlo = jax.jit(rt._lk_step, donate_argnums=(0, 1)).lower(
+        state, carries, desc).compile().as_text()
+    engine.dispose()
+
+    decode = _loop_copies(hlo, "lk.op.decode", "f32[2,8,48,64,128]")
+    assert any(k.endswith("lk.op.decode/while") for k in decode), decode
+    assert not any(decode.values()), decode
+    prefill = _loop_copies(hlo, "lk.op.prefill", "f32[2,1,48,64,128]")
+    assert prefill and not any(prefill.values()), prefill
