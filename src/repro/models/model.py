@@ -9,10 +9,18 @@ suitable for jit/pjit:
 * ``decode_step(params, caches, tokens, positions) -> (logits, caches)``
 * ``init_caches(batch, max_seq)``; ``cache_axes()``
 * ``input_specs(shape) -> (batch SDS tree, batch axes tree)``  (dry-run)
+* ``serve_params(params) -> params``: a private device copy of ``params``
+  in the served form, laid out once when a serving engine is built
+
+``decode_step`` and ``prefill`` take either form of the params: the tree
+``init`` returns (the published layout, which ``loss`` trains) or the one
+``serve_params`` returns, in which each SSM block's five input projections
+are one output-major ``in_proj`` (``ssm.ssm_serve_params``).
 """
 from __future__ import annotations
 
 import math
+import warnings
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -23,6 +31,7 @@ from repro.configs.base import ModelConfig, ShapeSpec
 from repro.distributed.sharding import Axes, ShardCtx, axes
 from repro.models import encdec as encdec_mod
 from repro.models import hybrid as hybrid_mod
+from repro.models import ssm as ssm_mod
 from repro.models import transformer as tfm
 from repro.models.layers import (Builder, embed_lookup, embed_params,
                                  rms_norm, sinusoidal_positions, unembed)
@@ -87,6 +96,18 @@ class Model:
     init_caches: Callable
     cache_axes: Callable
     input_specs: Callable
+    serve_params: Callable
+
+
+def _serve_tree(tree):
+    """``tree`` with every SSM block's params (a layer's ``"ssm"`` entry)
+    in the served form."""
+    if isinstance(tree, dict):
+        return {k: ssm_mod.ssm_serve_params(v) if k == "ssm"
+                else _serve_tree(v) for k, v in tree.items()}
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(_serve_tree(t) for t in tree)
+    return tree
 
 
 def build(cfg: ModelConfig, ctx: ShardCtx | None = None) -> Model:
@@ -289,7 +310,24 @@ def build(cfg: ModelConfig, ctx: ShardCtx | None = None) -> Model:
             ax["frames"] = axes("act_batch", None, "act_embed")
         return batch, ax
 
+    # -- served params -------------------------------------------------------
+    serve_jit = jax.jit(_serve_tree, donate_argnums=0)
+
+    def serve_params(params):
+        """A private device copy of ``params`` (host or device arrays) in
+        the served form: the caller's buffers stay valid, and the layout
+        is converted on the device in one call."""
+        params = jax.tree.map(jnp.array, params)
+        if cfg.family in ("ssm", "hybrid"):
+            with warnings.catch_warnings():
+                # the replaced projections are donated with the rest of
+                # the copy; no output has their shapes
+                warnings.filterwarnings(
+                    "ignore", "Some donated buffers were not usable")
+                params = serve_jit(params)
+        return params
+
     return Model(cfg=cfg, ctx=ctx, init=init, param_axes=param_axes,
                  loss=loss, prefill=prefill, decode_step=decode_step,
                  init_caches=init_caches, cache_axes=cache_axes,
-                 input_specs=input_specs)
+                 input_specs=input_specs, serve_params=serve_params)

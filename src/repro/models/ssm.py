@@ -59,13 +59,50 @@ def _causal_conv(x, w):
     return out.astype(x.dtype)
 
 
-def _project(p, u, ctx):
-    """u: (B,S,d) -> z,x,(B,S,H,P), Bm,Cm (B,S,N), dt (B,S,H) pre-activation."""
-    z = jnp.einsum("bsd,dhp->bshp", u, p["wz"])
-    x = jnp.einsum("bsd,dhp->bshp", u, p["wx"])
-    Bm = jnp.einsum("bsd,dn->bsn", u, p["wB"])
-    Cm = jnp.einsum("bsd,dn->bsn", u, p["wC"])
-    dt = jnp.einsum("bsd,dh->bsh", u, p["wdt"])
+_IN_PROJ = ("wz", "wx", "wB", "wC", "wdt")
+
+
+def ssm_serve_params(p):
+    """One SSM block's params in their served form.
+
+    The five input projections ``wz``, ``wx`` (d, H, Pd), ``wB``, ``wC``
+    (d, N) and ``wdt`` (d, H) become one output-major ``in_proj`` of shape
+    (2·H·Pd + 2N + H, d), rows in the order z, x, B, C, dt, as the
+    published Mamba2 checkpoint stores ``in_proj.weight``. Leading (layer)
+    axes are kept; every other leaf is unchanged. Stored (d, H, Pd), the
+    64-wide minor dimension is padded to 128 lanes on a TPU and every
+    decode step relays the stack out for its d-minor dot; output-major
+    with d minor it is read as it is stored.
+    """
+    d = p["wB"].shape[-2]
+    lead = p["wB"].shape[:-2]
+    cols = jnp.concatenate(
+        [p[k].reshape(*lead, d, -1) for k in _IN_PROJ], axis=-1)
+    out = {k: v for k, v in p.items() if k not in _IN_PROJ}
+    out["in_proj"] = jnp.swapaxes(cols, -1, -2)
+    return out
+
+
+def _project(p, u, cfg, ctx):
+    """u: (B,S,d) -> z,x,(B,S,H,P), Bm,Cm (B,S,N), dt (B,S,H) pre-activation.
+
+    Reads either form of the block's params: the published five
+    projections (``ssm_params``) or the served ``in_proj``
+    (``ssm_serve_params``)."""
+    if "in_proj" in p:
+        _, H, Pd, N = ssm_dims(cfg)
+        zxbcdt = jnp.einsum("bsd,nd->bsn", u, p["in_proj"])
+        z, x, Bm, Cm, dt = jnp.split(
+            zxbcdt, [H * Pd, 2 * H * Pd, 2 * H * Pd + N, 2 * H * Pd + 2 * N],
+            axis=-1)
+        z = z.reshape(*z.shape[:-1], H, Pd)
+        x = x.reshape(*x.shape[:-1], H, Pd)
+    else:
+        z = jnp.einsum("bsd,dhp->bshp", u, p["wz"])
+        x = jnp.einsum("bsd,dhp->bshp", u, p["wx"])
+        Bm = jnp.einsum("bsd,dn->bsn", u, p["wB"])
+        Cm = jnp.einsum("bsd,dn->bsn", u, p["wC"])
+        dt = jnp.einsum("bsd,dh->bsh", u, p["wdt"])
     # seq gathered inside the SSM block (SP); heads are the sharded dim
     x = ctx.constrain(x, "act_batch", None, "act_heads", None)
     z = ctx.constrain(z, "act_batch", None, "act_heads", None)
@@ -144,7 +181,7 @@ def ssm_block(p, u, cfg, ctx, *, return_state: bool = False):
     position (SSD running state + causal-conv input tails).
     """
     s = cfg.ssm
-    z, x, Bm, Cm, dt = _project(p, u, ctx)
+    z, x, Bm, Cm, dt = _project(p, u, cfg, ctx)
     x_raw, B_raw, C_raw = x, Bm, Cm
     x = jax.nn.silu(_causal_conv(x, p["conv_x"]))
     Bm = jax.nn.silu(_causal_conv(Bm, p["conv_B"]))
@@ -208,7 +245,7 @@ def ssm_block_decode(p, u, state, cfg, ctx):
     s = cfg.ssm
     # named scopes: a profiler trace's ops name the block's part they ran
     with jax.named_scope("mamba2.in_proj"):
-        z, x, Bm, Cm, dt = _project(p, u, ctx)
+        z, x, Bm, Cm, dt = _project(p, u, cfg, ctx)
     with jax.named_scope("mamba2.conv"):
         x1, cx = _conv_step(state["conv_x"], x[:, 0], p["conv_x"])
         B1, cB = _conv_step(state["conv_B"], Bm[:, 0], p["conv_B"])

@@ -136,10 +136,13 @@ class ServingEngine:
         if self.prefill_chunk_tokens < 1:
             raise ValueError("prefill_chunk_tokens must be >= 1")
 
-        caches = model.init_caches(max_batch, max_seq)
         # own a private copy: engine state is donated through every step /
-        # insert, which would otherwise invalidate the caller's param buffers
-        params = jax.tree.map(jnp.array, params)
+        # insert, which would otherwise invalidate the caller's param
+        # buffers. It is the served form, laid out once here for the step
+        # programs (no step relays out a weight); converted before any
+        # cache is allocated, as the conversion briefly holds both forms
+        params = model.serve_params(params)
+        caches = model.init_caches(max_batch, max_seq)
         # PER-SLOT prefill→decode handoff area: one staging row per engine
         # slot (batch-1 caches at the slot's batch index, first token,
         # prompt length — plus the staged prompt itself when prefill runs

@@ -144,11 +144,11 @@ def _loop_copies(hlo: str, scope: str, shape: str) -> dict[str, int]:
     return out
 
 
-def test_decode_layer_loop_does_not_copy_the_ssm_state(one_chip):
-    """The serving step program of a 2-layer, 8-slot mamba2-780m as the
-    runtime compiles it, state donated: no loop of the decode or prefill
-    work function copies the stacked SSD state (a carried stack was
-    copied in and out of the decode layer loop on every layer)."""
+@pytest.fixture(scope="module")
+def mamba2_step(one_chip):
+    """(compiled HLO text, served SSM params) of the serving step program
+    of a 2-layer, 8-slot mamba2-780m as ``ServingEngine`` builds it and
+    the runtime compiles it, state donated."""
     cfg = dataclasses.replace(get_config("mamba2-780m"), num_layers=2)
     model = build(cfg)
     params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
@@ -156,15 +156,68 @@ def test_decode_layer_loop_does_not_copy_the_ssm_state(one_chip):
     engine = ServingEngine(model, params, max_batch=8, max_seq=256,
                            chunked_prefill=True)
     rt = engine.rt
+    ssm = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                       rt.state["params"]["stack"]["blk0"]["ssm"])
     state, carries = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype), (rt.state, rt._carries))
     desc = _sds(one_chip, (mb.DESC_WIDTH,), jnp.int32)
     hlo = jax.jit(rt._lk_step, donate_argnums=(0, 1)).lower(
         state, carries, desc).compile().as_text()
     engine.dispose()
+    return hlo, ssm
 
+
+def _hlo_shape(dtype, dims) -> str:
+    short = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    return f"{short}[{','.join(map(str, dims))}]"
+
+
+def test_decode_layer_loop_does_not_copy_the_ssm_state(mamba2_step):
+    """No loop of the decode or prefill work function copies the stacked
+    SSD state (a carried stack was copied in and out of the decode layer
+    loop on every layer)."""
+    hlo, _ = mamba2_step
     decode = _loop_copies(hlo, "lk.op.decode", "f32[2,8,48,64,128]")
     assert any(k.endswith("lk.op.decode/while") for k in decode), decode
     assert not any(decode.values()), decode
     prefill = _loop_copies(hlo, "lk.op.prefill", "f32[2,1,48,64,128]")
     assert prefill and not any(prefill.values()), prefill
+
+
+_OPERANDS = re.compile(r"=\s*(?:\([^=]*?\)|\S+)\s+[\w\-]+\(([^)]*)\)")
+# an instruction's name and its (first) array shape
+_DEFINES = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\(?(\w+\[[\d,]*\])")
+
+
+def test_step_program_copies_no_ssm_weight(mamba2_step):
+    """The engine serves the Mamba2 input projection output-major
+    (``in_proj``, d minor): no copy in the step program has the shape of
+    an SSM weight or of one layer's slice of it (stored (d, H, Pd), the
+    stacked wz/wx were relaid out on every decode step and copied back on
+    every launch), and prefill's batch-1 ``mamba2.in_proj`` reads no
+    operand laid out (..., H, Pd) (the 64-wide rows padded to 128 lanes)."""
+    hlo, ssm = mamba2_step
+    assert "in_proj" in ssm and "wz" not in ssm, sorted(ssm)
+    weights = set()
+    for a in jax.tree.leaves(ssm):
+        weights |= {_hlo_shape(a.dtype, a.shape),
+                    _hlo_shape(a.dtype, a.shape[1:]),
+                    _hlo_shape(a.dtype, (1,) + a.shape[1:])}
+    copies = re.findall(r"=\s*(\w+\[[\d,]*\])\{[^}]*\}\s+copy\(", hlo)
+    assert copies, "no copy found: the HLO format changed"
+    assert not weights & set(copies), sorted(weights & set(copies))
+
+    shape_of = {}
+    for line in hlo.splitlines():
+        m = _DEFINES.match(line)
+        if m:
+            shape_of[m.group(1)] = m.group(2)
+    in_proj = [line for line in hlo.splitlines()
+               if "lk.op.prefill" in line and "mamba2.in_proj" in line]
+    assert in_proj
+    read = {shape_of[name]
+            for line in in_proj if (m := _OPERANDS.search(line))
+            for name in re.findall(r"%([\w.\-]+)", m.group(1))}
+    heads = re.compile(r"bf16\[(?:\d+,)*48,64\]")
+    assert read and not [s for s in read if heads.fullmatch(s)], sorted(read)
